@@ -48,8 +48,9 @@ SUITES = {
 }
 
 
-def main() -> None:
+def main() -> int:
     smoke = smoke_requested()
+    failed = []
     want = [a for a in sys.argv[1:] if a != "--smoke"]
     print("name,us_per_call,derived")
     for name, mod in SUITES.items():
@@ -66,10 +67,14 @@ def main() -> None:
             else:
                 for line in mod.run():
                     print(line)
-        except Exception as e:  # keep the suite going; surface the failure
+        except Exception as e:  # run the other suites; fail at the end
             print(f"{name}_FAILED,0,{e!r}")
+            failed.append(name)
         print(f"# suite {name} took {time.time() - t0:.1f}s", flush=True)
+    if failed:
+        print(f"# failed suites: {', '.join(failed)}", flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

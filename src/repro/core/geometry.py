@@ -269,9 +269,8 @@ def points_in_polygon_rows_jnp(points, poly_of_point, verts, nverts) -> np.ndarr
     the crossing test is exact comparisons, so results are identical)."""
     global _JNP_PIP_JIT
     import jax
-    from jax.experimental import enable_x64
     starts, ends, mask = polygon_edges(verts, nverts)
-    with enable_x64():
+    with jax.enable_x64(True):
         if _JNP_PIP_JIT is None:
             _JNP_PIP_JIT = jax.jit(_pip_rows_jnp_impl)
         out = _JNP_PIP_JIT(np.asarray(points, np.float64), starts, ends, mask,
@@ -558,8 +557,7 @@ def box_clip_areas_jnp(verts, nverts, boxes) -> np.ndarray:
     """
     global _JNP_CLIP_JIT
     import jax
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         if _JNP_CLIP_JIT is None:
             _JNP_CLIP_JIT = jax.jit(_box_clip_areas_jnp_impl)
         pts, cnt = _JNP_CLIP_JIT(np.asarray(verts, np.float64),

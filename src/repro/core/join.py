@@ -670,16 +670,15 @@ def contain_rows_jnp(X, xi, F, fi) -> np.ndarray:
     return _bucketed_rows_jnp("contain", X, xi, F, fi)
 
 
-def _overlap_rows_pallas(X, xi, Y, yi, interpret=None) -> np.ndarray:
+def _overlap_rows_pallas(X, xi, Y, yi) -> np.ndarray:
     """Bucketed overlap through the Pallas ``kernels/interval_join`` kernel
     (interpret mode off-TPU). Used by predicates without a fused kernel.
 
     Rows whose lists exceed ``_PALLAS_MAX_WIDTH`` would blow the kernel's
     padded [BB, I, J] VMEM tile; they take the flat host pass instead
     (verdict-identical by construction)."""
+    from ..kernels import interpret_mode, note_routed, pad_rows_pow2
     from ..kernels.interval_join.ops import batch_interval_overlap
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     xi = np.asarray(xi, np.int64)
     yi = np.asarray(yi, np.int64)
     N = len(xi)
@@ -689,14 +688,15 @@ def _overlap_rows_pallas(X, xi, Y, yi, interpret=None) -> np.ndarray:
     widths = np.maximum(np.maximum(cx, cy), 1)
     live = (cx > 0) & (cy > 0)
     wide = live & (widths > _PALLAS_MAX_WIDTH)
+    note_routed("filter_wide_rows_host", np.count_nonzero(wide))
     if wide.any():
         w = np.nonzero(wide)[0]
         out[w] = overlap_rows_np(X, xi[w], Y, yi[w])
     for sel in size_buckets(np.where(live & ~wide, widths, 0), _BUCKET_CHUNK):
-        xs, xl, nx = X.pack(xi[sel], _pow2(cx[sel].max()))
-        ys, yl, ny = Y.pack(yi[sel], _pow2(cy[sel].max()))
+        packed, n = pad_rows_pow2([*X.pack(xi[sel], _pow2(cx[sel].max())),
+                                   *Y.pack(yi[sel], _pow2(cy[sel].max()))])
         out[sel] = np.asarray(batch_interval_overlap(
-            xs, xl, nx, ys, yl, ny, interpret=interpret))
+            *packed, interpret=interpret_mode()))[:n]
     return out
 
 
@@ -754,15 +754,13 @@ def april_trichotomy_rows(
     return verdicts
 
 
-def _april_trichotomy_pallas(Xa, Xf, Ya, Yf, ri, si,
-                             interpret=None) -> np.ndarray:
+def _april_trichotomy_pallas(Xa, Xf, Ya, Yf, ri, si) -> np.ndarray:
     """Bucketed batches through the fused three-join Pallas kernel.
 
     Rows whose widest list exceeds ``_PALLAS_MAX_WIDTH`` take the flat host
     staged pass instead of blowing the kernel's VMEM tile."""
+    from ..kernels import interpret_mode, note_routed, pad_rows_pow2
     from ..kernels.interval_join.ops import batch_april_trichotomy
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     N = len(ri)
     verdicts = np.full(N, TRUE_NEG, np.int8)
     counts = [L.counts(idx) for L, idx in
@@ -771,6 +769,7 @@ def _april_trichotomy_pallas(Xa, Xf, Ya, Yf, ri, si,
     # rows with an empty A list on either side are decided without a kernel
     live = (counts[0] > 0) & (counts[2] > 0)
     wide = live & (widths > _PALLAS_MAX_WIDTH)
+    note_routed("filter_wide_rows_host", np.count_nonzero(wide))
     if wide.any():
         w = np.nonzero(wide)[0]
         verdicts[w] = april_trichotomy_rows(Xa, Xf, Ya, Yf, ri[w], si[w],
@@ -780,8 +779,9 @@ def _april_trichotomy_pallas(Xa, Xf, Ya, Yf, ri, si,
         rf = Xf.pack(ri[sel], _pow2(max(1, counts[1][sel].max())))
         sa = Ya.pack(si[sel], _pow2(counts[2][sel].max()))
         sf = Yf.pack(si[sel], _pow2(max(1, counts[3][sel].max())))
-        verdicts[sel] = np.asarray(batch_april_trichotomy(
-            *ra, *rf, *sa, *sf, interpret=interpret))
+        packed, n = pad_rows_pow2([*ra, *rf, *sa, *sf])
+        verdicts[sel] = batch_april_trichotomy(
+            *packed, interpret=interpret_mode())[:n]
     return verdicts
 
 

@@ -433,13 +433,11 @@ def _interval_words(store: RIStore, g: np.ndarray, W: int) -> np.ndarray:
 
 
 def _fragment_hits_pallas(store_x: RIStore, store_y: RIStore, gx, gy, lo, hi,
-                          xor_y: bool, interpret: bool | None = None,
-                          chunk_elems: int = 1 << 22) -> np.ndarray:
+                          xor_y: bool, chunk_elems: int = 1 << 22
+                          ) -> np.ndarray:
     """ALIGNEDAND over fragments through the Pallas `ri_and` word kernel."""
-    import jax
+    from ..kernels import interpret_mode, pad_rows_pow2
     from ..kernels.ri_and.ops import batch_aligned_and, xor_mask_words
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     F = len(gx)
     nbits = (3 * (hi - lo)).astype(np.int64)
     xo = (3 * (lo - store_x.ints[gx, 0])).astype(np.int64)
@@ -448,13 +446,16 @@ def _fragment_hits_pallas(store_x: RIStore, store_y: RIStore, gx, gy, lo, hi,
                        store_y.bit_off[gy + 1] - store_y.bit_off[gy])
     hits = np.zeros(F, bool)
     for sel in _size_buckets(ibits, chunk_elems):
-        W = max(1, (int(ibits[sel].max()) + 31) // 32)
+        # power-of-two word counts and rows: the kernel compiles
+        # logarithmically often, and zero words never AND non-zero
+        W = 1 << int(np.ceil(np.log2((int(ibits[sel].max()) + 31) // 32)))
         xw = _interval_words(store_x, gx[sel], W)
         yw = _interval_words(store_y, gy[sel], W)
         meta = np.stack([xo[sel], yo[sel], nbits[sel],
                          np.full(len(sel), int(xor_y))], axis=1).astype(np.int32)
+        (xw, yw, meta), n = pad_rows_pow2([xw, yw, meta])
         hits[sel] = np.asarray(batch_aligned_and(
-            xw, yw, meta, xor_mask_words(W), interpret=interpret))
+            xw, yw, meta, xor_mask_words(W), interpret=interpret_mode()))[:n]
     return hits
 
 

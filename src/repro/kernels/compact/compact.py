@@ -10,9 +10,14 @@ grid, so lanes of any length scan in one launch.
 
 Layout: the [N] mask arrives reshaped [R, 128] (int32 0/1, zero-padded);
 each grid step scans an [BR, 128] row block in row-major order — in-row
-exclusive cumsum plus row-exclusive block offsets plus the carry — and
+exclusive prefix sum plus row-exclusive block offsets plus the carry — and
 bumps the carry by the block's population count. The [1] total output is
 revisited by every step; the last step leaves the full count.
+
+Mosaic has no cumsum lowering, so both in-block prefix sums are log-step
+(Hillis-Steele) shifted adds: lane rotations by 1, 2, ..., 64 within a row,
+then sublane rotations by 1, 2, 4 across the block's row totals, each
+masked to the positions the rotation did not wrap into.
 """
 from __future__ import annotations
 
@@ -28,6 +33,17 @@ BLOCK_ROWS = 8
 LANES = 128
 
 
+def _inclusive_scan(x, axis: int):
+    """Log-step inclusive prefix sum of an int32 tile along ``axis``."""
+    n = x.shape[axis]
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    step = 1
+    while step < n:
+        x = x + jnp.where(pos >= step, pltpu.roll(x, step, axis), 0)
+        step *= 2
+    return x
+
+
 def _scan_kernel(m_ref, excl_ref, total_ref, carry_ref):
     b = pl.program_id(0)
 
@@ -36,11 +52,11 @@ def _scan_kernel(m_ref, excl_ref, total_ref, carry_ref):
         carry_ref[0] = 0
 
     m = m_ref[...]                              # [BR, 128] int32 0/1
-    rows = jnp.sum(m, axis=1)                   # [BR] per-row populations
-    base = jnp.cumsum(rows) - rows              # row-exclusive offsets
-    inrow = jnp.cumsum(m, axis=1) - m           # in-row exclusive cumsum
-    excl_ref[...] = carry_ref[0] + base[:, None] + inrow
-    carry_ref[0] = carry_ref[0] + jnp.sum(rows)
+    inrow = _inclusive_scan(m, 1)               # in-row inclusive sums
+    rows = jnp.broadcast_to(inrow[:, LANES - 1:], m.shape)   # row totals
+    base = _inclusive_scan(rows, 0) - rows      # row-exclusive offsets
+    excl_ref[...] = carry_ref[0] + base + inrow - m
+    carry_ref[0] = carry_ref[0] + jnp.sum(m)
     total_ref[0] = carry_ref[0]
 
 
@@ -60,7 +76,7 @@ def exclusive_scan_pallas(m2d, *, interpret: bool = False):
         in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda b: (b, 0))],
         out_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda b: (b, 0)),
-            pl.BlockSpec((1,), lambda b: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # [1] running total
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, LANES), jnp.int32),

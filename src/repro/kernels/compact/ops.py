@@ -12,13 +12,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode, note_routed
 from .compact import BLOCK_ROWS, LANES, exclusive_scan_pallas
 
 __all__ = ["compact_mask"]
 
-#: one pallas launch scans the whole lane from VMEM; longer lanes take the
-#: (identical) cumsum path rather than a multi-pass tiling
-_PALLAS_MAX = 1 << 21
+#: one pallas launch walks the lane in 1,024-element blocks, serially on
+#: the chip; lanes longer than 16k blocks take the (identical) cumsum path
+#: (counted in the routed-row counters) rather than a longer serial walk
+_PALLAS_MAX = 1 << 24
 
 
 @partial(jax.jit, static_argnames=("backend", "interpret"))
@@ -44,7 +46,7 @@ def _compact_impl(mask, *, backend: str, interpret: bool):
     return perm, k.astype(jnp.int32)
 
 
-def compact_mask(mask, *, backend: str = "jnp", interpret: bool | None = None):
+def compact_mask(mask, *, backend: str = "jnp"):
     """Stable front-pack of a device bool lane: (perm [N] int32, count []).
 
     ``perm[:count]`` are the True indices ascending, ``perm[count:]`` the
@@ -54,10 +56,9 @@ def compact_mask(mask, *, backend: str = "jnp", interpret: bool | None = None):
     blocked SMEM-carry scan kernel (interpret mode off-TPU); ``'jnp'`` the
     plain cumsum. Both are bit-identical to ``ref.compact_mask_ref``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if mask.shape[0] == 0:
         return jnp.zeros(0, jnp.int32), jnp.zeros((), jnp.int32)
     if backend == "pallas" and mask.shape[0] > _PALLAS_MAX:
+        note_routed("compact_long_lane_rows_jnp", mask.shape[0])
         backend = "jnp"
-    return _compact_impl(mask, backend=backend, interpret=interpret)
+    return _compact_impl(mask, backend=backend, interpret=interpret_mode())

@@ -22,6 +22,11 @@ def _pad_axis(a, axis, mult, fill):
     return jnp.pad(a, pad, constant_values=fill)
 
 
+def _count_col(n, block_b):
+    """[B] counts -> zero-padded [Bp, 1] int32 column (the kernel layout)."""
+    return _pad_axis(jnp.asarray(n, jnp.int32), 0, block_b, 0)[:, None]
+
+
 @partial(jax.jit, static_argnames=("interpret", "block_b", "block_j"))
 def batch_interval_overlap(xs, xl, nx, ys, yl, ny, *, interpret: bool = False,
                            block_b: int = 8, block_j: int = 128):
@@ -35,12 +40,11 @@ def batch_interval_overlap(xs, xl, nx, ys, yl, ny, *, interpret: bool = False,
     xl = _pad_axis(xl, 0, block_b, I32_MAX)
     ys = _pad_axis(ys, 0, block_b, I32_MAX)
     yl = _pad_axis(yl, 0, block_b, I32_MAX)
-    nx = _pad_axis(jnp.asarray(nx, jnp.int32), 0, block_b, 0)
-    ny = _pad_axis(jnp.asarray(ny, jnp.int32), 0, block_b, 0)
-    out = interval_overlap_pallas(xs, xl, nx, ys, yl, ny,
+    out = interval_overlap_pallas(xs, xl, _count_col(nx, block_b), ys, yl,
+                                  _count_col(ny, block_b),
                                   block_b=block_b, block_j=block_j,
                                   interpret=interpret)
-    return out[:B]
+    return out[:B, 0] != 0
 
 
 @partial(jax.jit, static_argnames=("interpret", "block_b"))
@@ -51,11 +55,10 @@ def _trichotomy_jit(nra, nrf, nsa, nsf, mats, *, interpret, block_b):
                                            I32_MAX), 0, block_b, I32_MAX),
                        _pad_axis(_pad_axis(jnp.asarray(l, jnp.int32), 1, 128,
                                            I32_MAX), 0, block_b, I32_MAX)))
-    counts = [_pad_axis(jnp.asarray(n, jnp.int32), 0, block_b, 0)
-              for n in (nra, nrf, nsa, nsf)]
+    counts = [_count_col(n, block_b) for n in (nra, nrf, nsa, nsf)]
     flat = [a for pair in padded for a in pair]
     return april_trichotomy_pallas(*counts, *flat, block_b=block_b,
-                                   interpret=interpret)
+                                   interpret=interpret)[:, 0]
 
 
 def batch_april_trichotomy(ras, ral, nra, rfs, rfl, nrf,

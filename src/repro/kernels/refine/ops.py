@@ -32,4 +32,4 @@ def batch_edges_intersect(a0, a1, am, b0, b1, bm, *, eps=1e-5, interpret=False):
     arrs = [_pad(x, 0, 8, 0) for x in (a0, a1)] + [_pad(am, 0, 8, False)] \
         + [_pad(x, 0, 8, 0) for x in (b0, b1)] + [_pad(bm, 0, 8, False)]
     hit, unc = edges_intersect_pallas(*arrs, eps=eps, interpret=interpret)
-    return hit[:B], unc[:B]
+    return hit[:B, 0] != 0, unc[:B, 0] != 0

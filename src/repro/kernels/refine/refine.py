@@ -10,6 +10,10 @@ f32 on device with an epsilon guard band: any orientation magnitude below
 ``eps`` (relative) makes the pair *uncertain* rather than decided; the
 driver re-checks uncertain pairs on host at f64. Definite hits/misses never
 contradict the exact predicate (tested against the f64 oracle).
+
+TPU layout: edge masks arrive as int32 0/1 planes and the per-pair
+verdicts leave as [B, 1] int32 columns — Mosaic neither tiles a rank-1
+block of BB < 128 rows nor relayouts i1 vectors into the 3-D tile.
 """
 from __future__ import annotations
 
@@ -20,6 +24,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 __all__ = ["edges_intersect_pallas"]
+
+
+def _any_rows(pred):
+    """[BB, Ea, EB] bool -> [BB, 1] int32 0/1 (any over the edge tile)."""
+    return jnp.max(jnp.max(pred.astype(jnp.int32), axis=2), axis=1,
+                   keepdims=True)
 
 
 def _kernel(a0x_ref, a0y_ref, a1x_ref, a1y_ref, am_ref,
@@ -47,7 +57,7 @@ def _kernel(a0x_ref, a0y_ref, a1x_ref, a1y_ref, am_ref,
     d3 = orient(A0x, A0y, A1x, A1y, B0x, B0y)
     d4 = orient(A0x, A0y, A1x, A1y, B0x * 0 + B1x, B0y * 0 + B1y)
 
-    valid = am[:, :, None] & bm[:, None, :]
+    valid = am[:, :, None] * bm[:, None, :] > 0
     proper = ((d1 > 0) != (d2 > 0)) & ((d3 > 0) != (d4 > 0))
 
     # relative guard band: |orient| below eps * scale * (scale + mag). The
@@ -68,8 +78,8 @@ def _kernel(a0x_ref, a0y_ref, a1x_ref, a1y_ref, am_ref,
              & (jnp.minimum(A0y, A1y) <= jnp.maximum(B0y, B1y) + tol)
              & (jnp.minimum(B0y, B1y) <= jnp.maximum(A0y, A1y) + tol))
 
-    hit = jnp.any(proper & ~near0 & valid, axis=(1, 2))
-    unc = jnp.any(near0 & boxes & valid, axis=(1, 2))
+    hit = _any_rows(proper & ~near0 & valid)
+    unc = _any_rows(near0 & boxes & valid)
 
     @pl.when(jb == 0)
     def _():
@@ -78,14 +88,18 @@ def _kernel(a0x_ref, a0y_ref, a1x_ref, a1y_ref, am_ref,
 
     @pl.when(jb != 0)
     def _():
-        hit_ref[...] = hit_ref[...] | hit
-        unc_ref[...] = unc_ref[...] | unc
+        hit_ref[...] = jnp.maximum(hit_ref[...], hit)
+        unc_ref[...] = jnp.maximum(unc_ref[...], unc)
 
 
 def edges_intersect_pallas(a0, a1, am, b0, b1, bm, *, eps: float = 1e-5,
                            block_b: int = 8, block_e: int = 128,
                            interpret: bool = False):
-    """(hit [B], uncertain [B]). a0/a1: [B, Ea, 2] f32; b0/b1: [B, Eb, 2]."""
+    """(hit [B, 1], uncertain [B, 1]) int32 0/1.
+
+    a0/a1: [B, Ea, 2] f32; b0/b1: [B, Eb, 2]; am/bm: [B, Ea] / [B, Eb]
+    int32 0/1 edge masks.
+    """
     B, Ea, _ = a0.shape
     Eb = b0.shape[1]
     assert B % block_b == 0 and Eb % block_e == 0
@@ -99,14 +113,15 @@ def edges_intersect_pallas(a0, a1, am, b0, b1, bm, *, eps: float = 1e-5,
 
     spec_a = pl.BlockSpec((block_b, Ea), lambda b, j: (b, 0))
     spec_b = pl.BlockSpec((block_b, block_e), lambda b, j: (b, j))
-    spec_o = pl.BlockSpec((block_b,), lambda b, j: (b,))
+    spec_o = pl.BlockSpec((block_b, 1), lambda b, j: (b, 0))
 
     return pl.pallas_call(
         partial(_kernel, eps=eps),
         grid=grid,
         in_specs=[spec_a] * 4 + [spec_a] + [spec_b] * 4 + [spec_b],
         out_specs=(spec_o, spec_o),
-        out_shape=(jax.ShapeDtypeStruct((B,), jnp.bool_),
-                   jax.ShapeDtypeStruct((B,), jnp.bool_)),
+        out_shape=(jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1), jnp.int32)),
         interpret=interpret,
-    )(a0x, a0y, a1x, a1y, am, b0x, b0y, b1x, b1y, bm)
+    )(a0x, a0y, a1x, a1y, jnp.asarray(am, jnp.int32),
+      b0x, b0y, b1x, b1y, jnp.asarray(bm, jnp.int32))

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .ri_and import aligned_and_pallas
+from .ri_and import BLOCK_ROWS, aligned_and_pallas
 
 
 def pack_bits_u32(bits: np.ndarray, W: int) -> np.ndarray:
@@ -30,9 +30,26 @@ def xor_mask_words(W: int, pattern=(1, 1, 0)) -> np.ndarray:
     return pack_bits_u32(bits, W)
 
 
+def _pad_to(a, axis, mult):
+    size = a.shape[axis]
+    pad = [(0, 0)] * a.ndim
+    pad[axis] = (0, -(-size // mult) * mult - size)
+    return jnp.pad(a, pad)
+
+
 @partial(jax.jit, static_argnames=("interpret",))
 def batch_aligned_and(x_words, y_words, meta, mask_words, *, interpret=False):
-    return aligned_and_pallas(
-        jnp.asarray(x_words, jnp.uint32), jnp.asarray(y_words, jnp.uint32),
-        jnp.asarray(meta, jnp.int32), jnp.asarray(mask_words, jnp.uint32),
+    """[B] bool ALIGNEDAND verdicts; pads rows to the kernel's block and
+    words to whole 128-lane vectors (zero words never AND non-zero)."""
+    B = x_words.shape[0]
+
+    def words(w):
+        return _pad_to(_pad_to(jnp.asarray(w, jnp.uint32), 1, 128),
+                       0, BLOCK_ROWS)
+
+    out = aligned_and_pallas(
+        words(x_words), words(y_words),
+        _pad_to(jnp.asarray(meta, jnp.int32), 0, BLOCK_ROWS),
+        _pad_to(jnp.asarray(mask_words, jnp.uint32)[None, :], 1, 128),
         interpret=interpret)
+    return out[:B, 0] != 0

@@ -2,85 +2,101 @@
 
 The paper aligns two interval bitstrings byte-by-byte with carry-over and
 ANDs them, early-exiting on the first non-zero byte. Byte loops are scalar
-poison on TPU; here each grid program aligns one fragment pair with
-*vectorized 32-bit funnel shifts* over the whole word vector (roll + shift),
-applies the optional XOR re-encoding mask (same-encoding joins) and the tail
-mask, and reduces with a single any().
+poison on TPU; here each grid program aligns a block of fragment pairs with
+*vectorized 32-bit funnel shifts* over the whole word vector, applies the
+optional XOR re-encoding mask (same-encoding joins) and the tail mask, and
+reduces with a single any() per row.
 
 Codes are packed LSB-first: stream bit ``3c+t`` is bit ``(3c+t) % 32`` of
 word ``(3c+t) // 32`` (t = position inside the cell's 3-bit code). Fragments
 start on cell boundaries, so the XOR mask's phase is always 0 and the mask
 word pattern (period lcm(3,32) = 3 words) is passed in precomputed.
 
-TPU note: one fragment pair per grid step keeps the shifts scalar-uniform
-(per-row funnel shifts would need lane gathers). Fragment words W is tiny
-(3·cells/32), so the batch axis is the throughput axis — on real hardware
-multiple pairs pipeline through the sequential grid with negligible VMEM
-pressure, and the hot path of APRIL never calls this kernel (RI only).
+TPU layout: a block holds ``BB`` (= 8, the int32 sublane tile) pair rows
+of ``W`` words (a multiple of 128 lanes). Every row has its own bit offset,
+so the word alignment is a log-step barrel shifter — static lane rotations
+by 1, 2, 4, ... words, each selected per row by one bit of the row's word
+offset — with no lane gather. The rotation is circular over ``W``: callers
+keep ``off_bits + n_bits <= 32 * W`` (a fragment never runs past its own
+interval's words), so wrapped words never reach the tail mask.
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["aligned_and_pallas"]
+__all__ = ["aligned_and_pallas", "BLOCK_ROWS"]
+
+#: pair rows per grid step (the int32 sublane tile)
+BLOCK_ROWS = 8
 
 
-def _funnel_align(words, off_bits, W):
-    """Extract W words starting at bit offset ``off_bits`` from ``words``."""
+def _rotate_left(x, k: int):
+    """Circular lane rotation: out[:, i] = x[:, (i + k) % W]."""
+    W = x.shape[1]
+    return pltpu.roll(x, (W - k) % W, 1)
+
+
+def _funnel_align(words, off_bits):
+    """Per-row extraction of the word stream starting at bit ``off_bits``
+    ([BB, 1] int32) from ``words`` ([BB, W] uint32)."""
+    W = words.shape[1]
     off_w = off_bits // 32
+    cur = words
+    step = 1
+    while step < W:                       # barrel shifter over word offsets
+        take = ((off_w // step) % 2) == 1
+        cur = jnp.where(take, _rotate_left(cur, step), cur)
+        step *= 2
+    nxt = _rotate_left(cur, 1)
     sh = (off_bits % 32).astype(jnp.uint32)
-    cur = jnp.roll(words, -off_w)
-    nxt = jnp.roll(words, -(off_w + 1))
     hi_sh = (jnp.uint32(32) - sh) % jnp.uint32(32)
-    shifted = (cur >> sh) | jnp.where(sh == 0, jnp.uint32(0), nxt << hi_sh)
-    return shifted
+    return (cur >> sh) | jnp.where(sh == 0, jnp.uint32(0), nxt << hi_sh)
 
 
 def _kernel(meta_ref, x_ref, y_ref, mask_ref, out_ref):
-    # meta row: [1,4] int32 = (x_off_bits, y_off_bits, n_bits, xor_y)
-    x_off = meta_ref[0, 0]
-    y_off = meta_ref[0, 1]
-    n_bits = meta_ref[0, 2]
-    xor_y = meta_ref[0, 3]
+    # meta rows: [BB, 4] int32 = (x_off_bits, y_off_bits, n_bits, xor_y)
+    meta = meta_ref[...]
+    x_off = meta[:, 0:1]
+    y_off = meta[:, 1:2]
+    n_bits = meta[:, 2:3]
+    xor_y = meta[:, 3:4]
 
-    xw = x_ref[0]             # [W] uint32
-    yw = y_ref[0]
-    mask = mask_ref[...]      # [W] uint32 repeating XOR pattern (phase 0)
-    W = xw.shape[0]
-
-    ax = _funnel_align(xw, x_off, W)
-    ay = _funnel_align(yw, y_off, W)
-    ay = jnp.where(xor_y != 0, ay ^ mask, ay)
+    ax = _funnel_align(x_ref[...], x_off)
+    ay = _funnel_align(y_ref[...], y_off)
+    ay = jnp.where(xor_y != 0, ay ^ mask_ref[...], ay)
 
     # tail mask: word k keeps bits [0, clamp(n_bits - 32k, 0, 32))
-    k = jax.lax.broadcasted_iota(jnp.int32, (W,), 0)
+    k = jax.lax.broadcasted_iota(jnp.int32, ax.shape, 1)
     rem = jnp.clip(n_bits - 32 * k, 0, 32)
-    full = rem >= 32
-    tail = (jnp.uint32(1) << rem.astype(jnp.uint32)) - jnp.uint32(1)
-    keep = jnp.where(full, jnp.uint32(0xFFFFFFFF), tail)
+    tail = (jnp.uint32(1) << jnp.minimum(rem, 31).astype(jnp.uint32)) \
+        - jnp.uint32(1)
+    keep = jnp.where(rem >= 32, jnp.uint32(0xFFFFFFFF), tail)
 
-    out_ref[0, 0] = jnp.any((ax & ay & keep) != 0)
+    hit = ((ax & ay & keep) != 0).astype(jnp.int32)
+    out_ref[...] = jnp.max(hit, axis=1, keepdims=True)
 
 
 def aligned_and_pallas(x_words, y_words, meta, mask_words, *,
                        interpret: bool = False):
-    """[B] bool. x_words/y_words: [B, W] uint32; meta: [B, 4] int32
-    (x_off_bits, y_off_bits, n_bits, xor_y); mask_words: [W] uint32."""
+    """[B, 1] int32 0/1. x_words/y_words: [B, W] uint32 with B a multiple of
+    ``BLOCK_ROWS`` and W of 128; meta: [B, 4] int32 (x_off_bits,
+    y_off_bits, n_bits, xor_y); mask_words: [1, W] uint32."""
     B, W = x_words.shape
+    assert B % BLOCK_ROWS == 0 and W % 128 == 0, (B, W)
+    rows = pl.BlockSpec((BLOCK_ROWS, W), lambda b: (b, 0))
     return pl.pallas_call(
         _kernel,
-        grid=(B,),
+        grid=(B // BLOCK_ROWS,),
         in_specs=[
-            pl.BlockSpec((1, 4), lambda b: (b, 0)),
-            pl.BlockSpec((1, W), lambda b: (b, 0)),
-            pl.BlockSpec((1, W), lambda b: (b, 0)),
-            pl.BlockSpec((W,), lambda b: (0,)),
+            pl.BlockSpec((BLOCK_ROWS, 4), lambda b: (b, 0)),
+            rows,
+            rows,
+            pl.BlockSpec((1, W), lambda b: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.bool_),
+        out_specs=pl.BlockSpec((BLOCK_ROWS, 1), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=interpret,
-    )(meta, x_words, y_words, mask_words)[:, 0]
+    )(meta, x_words, y_words, mask_words)

@@ -29,6 +29,7 @@ import numpy as np
 
 from ..datagen import make_dataset
 from ..runtime.checkpoint import CheckpointManager
+from ..runtime.compile_cache import enable_compile_cache
 from ..spatial import JoinService
 from ..spatial.filters import available_filters
 
@@ -165,6 +166,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     report = run_serve(
         dataset=args.dataset, count=args.count,
         query_layer=args.query_layer, n_queries=args.n_queries,

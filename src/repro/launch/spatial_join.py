@@ -35,7 +35,9 @@ import numpy as np
 from ..core import partition as partition_mod
 from ..core.join import INDECISIVE, TRUE_HIT
 from ..datagen import PolygonDataset, make_dataset
+from ..kernels import count_routed
 from ..runtime.checkpoint import CheckpointManager
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.elastic import WorkQueue
 from ..spatial import refine
 from ..spatial.distributed import (distributed_filter, distributed_fused_join,
@@ -241,24 +243,28 @@ def run_join(r_name="T1", s_name="T2", n_order=8, parts=2, ckpt_dir=None,
     totals = {"true_neg": 0, "true_hit": 0, "indecisive": 0,
               "refined_true": 0}
     t0 = time.perf_counter()
-    while not queue.finished:
-        p = queue.acquire()
-        if p is None:
-            break
-        res, counts = join_partition(R, S, approx_r, approx_s, parting, p,
-                                     mesh, filt, backend=backend,
-                                     refine_backend=refine_backend,
-                                     mbr_backend=mbr_backend,
-                                     pipeline_mode=pipeline_mode,
-                                     plan_mode=plan_mode, n_order=n_order,
-                                     profile_cache=profile_cache)
-        done[p] = res
-        for k in totals:
-            totals[k] += counts.get(k, 0)
-        queue.complete(p)
-        if mgr is not None:
-            mgr.save(len(done), {f"part_{k}": v for k, v in done.items()})
+    with count_routed() as routed:
+        while not queue.finished:
+            p = queue.acquire()
+            if p is None:
+                break
+            res, counts = join_partition(R, S, approx_r, approx_s, parting,
+                                         p, mesh, filt, backend=backend,
+                                         refine_backend=refine_backend,
+                                         mbr_backend=mbr_backend,
+                                         pipeline_mode=pipeline_mode,
+                                         plan_mode=plan_mode,
+                                         n_order=n_order,
+                                         profile_cache=profile_cache)
+            done[p] = res
+            for k in totals:
+                totals[k] += counts.get(k, 0)
+            queue.complete(p)
+            if mgr is not None:
+                mgr.save(len(done),
+                         {f"part_{k}": v for k, v in done.items()})
     t_join = time.perf_counter() - t0
+    totals["routed"] = routed
     if mgr is not None:
         mgr.wait()
 
@@ -292,20 +298,22 @@ def run_tiled_join(r_name="T1", s_name="T2", *, tile_budget: int,
     check_pipeline_mode(pipeline_mode)
     check_plan_mode(plan_mode)
     profile_cache = ProfileCache() if plan_mode == "adaptive" else None
-    pairs, stats = tiled_join(
-        iter_dataset_chunks(r_name, seed=seed, count=count_r,
-                            chunk_size=chunk_size),
-        iter_dataset_chunks(s_name, seed=seed + 1, count=count_s,
-                            chunk_size=chunk_size),
-        method=method, n_order=n_order, filter_backend=backend,
-        refine_backend=refine_backend, mbr_backend=mbr_backend,
-        pipeline_mode=pipeline_mode, plan_mode=plan_mode, mesh=mesh,
-        ckpt_dir=ckpt_dir, resume=resume, profile_cache=profile_cache,
-        tile_budget=tile_budget, balance=balance, seed=seed)
+    with count_routed() as routed:
+        pairs, stats = tiled_join(
+            iter_dataset_chunks(r_name, seed=seed, count=count_r,
+                                chunk_size=chunk_size),
+            iter_dataset_chunks(s_name, seed=seed + 1, count=count_s,
+                                chunk_size=chunk_size),
+            method=method, n_order=n_order, filter_backend=backend,
+            refine_backend=refine_backend, mbr_backend=mbr_backend,
+            pipeline_mode=pipeline_mode, plan_mode=plan_mode, mesh=mesh,
+            ckpt_dir=ckpt_dir, resume=resume, profile_cache=profile_cache,
+            tile_budget=tile_budget, balance=balance, seed=seed)
+    stats.extra["routed"] = routed
     resumed = stats.extra.get("resumed_tiles", 0)
     print(f"tiles {stats.tiles} ({resumed} resumed)  "
           f"partition {stats.t_partition:.2f}s  build {stats.t_build:.2f}s  "
-          f"results {len(pairs)}")
+          f"results {len(pairs)}  routed rows {routed}")
     print(stats.row())
     return pairs, stats
 
@@ -363,6 +371,7 @@ def main():
                     help="tiled driver only: generated objects per "
                          "streamed chunk")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.tile_budget is not None:
         run_tiled_join(args.r, args.s, tile_budget=args.tile_budget,
                        n_order=args.n_order, balance=args.balance,

@@ -45,12 +45,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # stable alias, jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 from ..core.join import INDECISIVE, TRUE_HIT, TRUE_NEG, pack_lists
+from ..kernels import note_routed, pad_rows_pow2
 from .fused import to_host
 
 __all__ = [
@@ -122,7 +120,8 @@ def bucket_pairs(store_r, store_s, pairs: np.ndarray, n_devices: int = 1,
     """Split a ``[N, 2]`` pair batch into power-of-two interval-width
     buckets and pack each (DESIGN.md §9): width-bucketing bounds padding
     waste and is the primary load-balance/straggler lever of the sharded
-    filter stage."""
+    filter stage. Each bucket's rows pad to ``n_devices`` times a power of
+    two, so the mesh step compiles once per size class, not per bucket."""
     pairs = np.asarray(pairs, np.int64).reshape(-1, 2)
     if len(pairs) == 0:
         return []
@@ -134,7 +133,9 @@ def bucket_pairs(store_r, store_s, pairs: np.ndarray, n_devices: int = 1,
         b = 1 << int(np.ceil(np.log2(min(int(w), max_width))))
         buckets.setdefault(max(b, 8), []).append(k)
     return [
-        pack_pair_batch(store_r, store_s, pairs[idx], pad_batch_to=n_devices,
+        pack_pair_batch(store_r, store_s, pairs[idx],
+                        pad_batch_to=n_devices * (1 << int(np.ceil(np.log2(
+                            -(-len(idx) // n_devices))))),
                         pad_width_to=bw)
         for bw, idx in sorted(buckets.items())
     ]
@@ -182,16 +183,13 @@ def make_join_mesh(n_devices: int | None = None) -> Mesh:
     return Mesh(np.asarray(devs[:n]), ("data",))
 
 
-def distributed_april_filter(packed: PackedPairs, mesh: Mesh | None = None):
-    """Run the APRIL filter kernel on one packed batch, sharded over the
-    mesh 'data' axis (DESIGN.md §9).
+_FILTER_STEP_CACHE: dict = {}
 
-    Returns (verdicts [B] np.int8, counts dict) — counts are psum-reduced on
-    device (one scalar per verdict class crosses the network, not the batch).
-    """
-    mesh = mesh or make_join_mesh()
-    batch = packed.arrays()
-    valid = packed.valid
+
+def _filter_shard_step(mesh):
+    """The sharded APRIL filter step, jitted once per mesh."""
+    if mesh in _FILTER_STEP_CACHE:
+        return _FILTER_STEP_CACHE[mesh]
 
     @partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
              out_specs=(P("data"), P()))
@@ -204,8 +202,21 @@ def distributed_april_filter(packed: PackedPairs, mesh: Mesh | None = None):
         counts = jax.lax.psum(counts, "data")
         return verd, counts
 
-    verd, counts = jax.jit(step)(
-        {k: jnp.asarray(a) for k, a in batch.items()}, jnp.asarray(valid))
+    _FILTER_STEP_CACHE[mesh] = jax.jit(step)
+    return _FILTER_STEP_CACHE[mesh]
+
+
+def distributed_april_filter(packed: PackedPairs, mesh: Mesh | None = None):
+    """Run the APRIL filter kernel on one packed batch, sharded over the
+    mesh 'data' axis (DESIGN.md §9).
+
+    Returns (verdicts [B] np.int8, counts dict) — counts are psum-reduced on
+    device (one scalar per verdict class crosses the network, not the batch).
+    """
+    mesh = mesh or make_join_mesh()
+    verd, counts = _filter_shard_step(mesh)(
+        {k: jnp.asarray(a) for k, a in packed.arrays().items()},
+        jnp.asarray(packed.valid))
     verd, counts = to_host(verd, counts)
     return (verd,
             {"true_neg": int(counts[0]), "true_hit": int(counts[1]),
@@ -279,8 +290,7 @@ def distributed_mbr_join(mbrs_r: np.ndarray, mbrs_s: np.ndarray,
     emits the pair list on host — identical to ``mbr_join`` on every
     backend. Returns (pairs [K,2] int64, counts dict).
     """
-    from .mbr_join import _pad_rows_pow2, _prepare, candidate_rows
-    from jax.experimental import enable_x64
+    from .mbr_join import _prepare, candidate_rows
 
     mbrs_r, mbrs_s, k, extent = _prepare(mbrs_r, mbrs_s, grid)
     if k == 0:
@@ -295,12 +305,12 @@ def distributed_mbr_join(mbrs_r: np.ndarray, mbrs_s: np.ndarray,
     n_dev = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
     # replicated tables pad to powers of two as well, so the shard step
     # compiles O(log) times across partition-sized inputs, not per shape
-    (mbrs_r, lo_r), _ = _pad_rows_pow2([mbrs_r, lo_r])
-    (mbrs_s, lo_s), _ = _pad_rows_pow2([mbrs_s, lo_s])
-    (pri, psi, pox, poy, valid), n = _pad_rows_pow2(
+    (mbrs_r, lo_r), _ = pad_rows_pow2([mbrs_r, lo_r])
+    (mbrs_s, lo_s), _ = pad_rows_pow2([mbrs_s, lo_s])
+    (pri, psi, pox, poy, valid), n = pad_rows_pow2(
         [ri, si, own_x, own_y, np.ones(len(ri), bool)], multiple=n_dev)
     step = _mbr_shard_step(mesh)
-    with enable_x64():
+    with jax.enable_x64(True):
         keep, count = step(*[jnp.asarray(a) for a in (
             mbrs_r, mbrs_s, lo_r, lo_s, pri, psi, pox, poy, valid)])
     keep_h, count_h = to_host(keep, count)
@@ -340,17 +350,24 @@ def distributed_refine(R, S, pairs: np.ndarray,
                        mesh: Mesh | None = None):
     """Refine indecisive candidate pairs sharded over the mesh 'data' axis.
 
-    Pairs are processed in vertex-count-bucketed chunks (the padded
-    [N, Er, Es] working set stays bounded, as on the host backends); each
-    device runs the batched jnp refinement core (f64 under ``enable_x64``)
-    on its shard, and the count of device-decided hits is psum-reduced on
-    device (one scalar per chunk crosses the network). Pairs whose sign
-    evaluations fall inside the FMA guard band come back uncertain and are
-    re-run on host, so the final verdicts are identical to the host
-    backends. Returns (results [N] bool, counts dict).
+    Pairs are split into width classes by their vertex counts rounded up
+    to powers of two. A class that fills at least one chunk (``n_dev``
+    times :func:`~repro.spatial.refine.device_chunk_rows` rows) gathers
+    only its own vertex widths; the remaining pairs share one class at the
+    datasets' full padded widths. So a typical pair's work scales with its
+    own class's widths, not with the dataset's largest polygon, while every
+    compiled shape beyond the full-width one is paid for by a full chunk
+    of work. Each class runs in fixed-shape chunks (a power-of-two chunk
+    when it is smaller, the last one padded), so the padded [rows, Er, Es]
+    working set per device stays bounded. Each device runs the batched jnp
+    refinement core (f64 under ``jax.enable_x64``) on its shard, and the
+    count of device-decided hits is psum-reduced on device (one scalar per
+    chunk crosses the network). Pairs whose sign evaluations fall inside
+    the FMA guard band come back uncertain and are re-run on host, so the
+    final verdicts are identical to the host backends. Returns (results
+    [N] bool, counts dict).
     """
     from . import refine as refine_mod
-    from jax.experimental import enable_x64
 
     pairs = np.asarray(pairs, np.int64).reshape(-1, 2)
     N = len(pairs)
@@ -362,40 +379,52 @@ def distributed_refine(R, S, pairs: np.ndarray,
     body = (refine_mod._within_impl_jnp if predicate == "within"
             else refine_mod._line_impl_jnp if predicate == "linestring"
             else refine_mod._intersects_impl_jnp)
-    if intersectsish:
-        rep_r = refine_mod._reps(R, pairs[:, 0])
-        rep_s = refine_mod._reps(S, pairs[:, 1])
+    step = _refine_shard_step(body, mesh, 6 if intersectsish else 4)
 
+    def pow2(n):
+        """Elementwise next power of two of counts ``n`` (at least 1)."""
+        return 1 << np.ceil(np.log2(np.maximum(n, 1))).astype(np.int64)
+
+    Wa, Wb = R.verts.shape[1], S.verts.shape[1]
+    wa = np.minimum(pow2(R.nverts[pairs[:, 0]]), Wa)
+    wb = np.minimum(pow2(S.nverts[pairs[:, 1]]), Wb)
+    classes, inv, size = np.unique(np.stack([wa, wb], axis=1), axis=0,
+                                   return_inverse=True, return_counts=True)
+    full = np.array([n_dev * refine_mod.device_chunk_rows(a, b)
+                     for a, b in classes.tolist()])
+    small = (size < full)[inv.reshape(-1)]   # classes below one chunk
+    wa[small], wb[small] = Wa, Wb
     out = np.zeros(N, bool)
     n_true = 0
-    for sel, p, vr, nr, vs, ns in refine_mod.iter_pair_chunks(R, S, pairs):
-        Bp = max(n_dev, ((len(p) + n_dev - 1) // n_dev) * n_dev)
-
-        def pad(x, fill=0):
-            if len(x) == Bp:
-                return x
-            ext = np.full((Bp - len(x),) + x.shape[1:], fill, x.dtype)
-            return np.concatenate([x, ext], axis=0)
-
-        args = [pad(vr), pad(nr), pad(vs), pad(ns)]
-        if intersectsish:
-            args += [pad(rep_r[sel]), pad(rep_s[sel])]
-        valid = pad(np.ones(len(p), bool), False)
-
-        step = _refine_shard_step(body, mesh, len(args))
-        with enable_x64():
-            res, unc, count = step(*[jnp.asarray(a) for a in args],
-                                   jnp.asarray(valid))
-        res_h, unc_h, count_h = to_host(res, unc, count)
-        res_h = res_h[: len(p)].copy()
-        unc_h = unc_h[: len(p)]
-        n_true += int(count_h)
-        if unc_h.any():    # guard-band pairs: exact host re-check
-            res_h[unc_h] = refine_mod.refine(R, S, p[unc_h],
-                                             predicate=predicate,
-                                             backend="numpy")
-            n_true += int(res_h[unc_h].sum())
-        out[sel] = res_h
+    for Va, Vb in sorted(set(zip(wa.tolist(), wb.tolist()))):
+        sel = np.flatnonzero((wa == Va) & (wb == Vb))
+        C = n_dev * refine_mod.device_chunk_rows(Va, Vb)
+        if len(sel) < C:    # a power-of-two chunk
+            C = n_dev * int(pow2(-(-len(sel) // n_dev)))
+        for c0 in range(0, len(sel), C):
+            rows = sel[c0:c0 + C]
+            p = pairs[rows]
+            args = [R.verts[:, :Va][p[:, 0]], R.nverts[p[:, 0]],
+                    S.verts[:, :Vb][p[:, 1]], S.nverts[p[:, 1]]]
+            if intersectsish:
+                args += [refine_mod._reps(R, p[:, 0]),
+                         refine_mod._reps(S, p[:, 1])]
+            args.append(np.ones(len(p), bool))          # valid rows
+            args = [np.concatenate([a, np.zeros((C - len(p),) + a.shape[1:],
+                                                a.dtype)]) for a in args]
+            with jax.enable_x64(True):
+                res, unc, count = step(*[jnp.asarray(a) for a in args])
+            res_h, unc_h, count_h = to_host(res, unc, count)
+            res_h = res_h[: len(p)].copy()
+            unc_h = unc_h[: len(p)]
+            n_true += int(count_h)
+            note_routed(refine_mod._ESCALATED, np.count_nonzero(unc_h))
+            if unc_h.any():    # guard-band pairs: exact host re-check
+                res_h[unc_h] = refine_mod.refine(R, S, p[unc_h],
+                                                 predicate=predicate,
+                                                 backend="numpy")
+                n_true += int(res_h[unc_h].sum())
+            out[rows] = res_h
     return out, {"refined_true": n_true}
 
 
@@ -407,15 +436,18 @@ def distributed_refine(R, S, pairs: np.ndarray,
 _FUSED_STEP_CACHE: dict = {}
 
 
-def _fused_shard_step(mesh, with_filter: bool = True):
-    """The one-dispatch chain, compiled per (mesh, filter-on/off).
+def _fused_shard_step(mesh, with_filter: bool, chunk: int):
+    """The one-dispatch chain, compiled per (mesh, filter-on/off, chunk).
 
     ``with_filter=False`` is the per-shard plan's skip-filter variant
     (DESIGN.md §13): no interval batch enters the step — every valid row
     is INDECISIVE and refines, so tiny candidate sets avoid the packing
     and kernel work entirely while staying inside one ``shard_map``.
+    Refinement walks each shard's rows ``chunk`` at a time
+    (:func:`~repro.spatial.refine.map_row_chunks`), so the f64
+    [rows, Er, Es] tile never materializes whole.
     """
-    key = (mesh, with_filter)
+    key = (mesh, with_filter, chunk)
     if key in _FUSED_STEP_CACHE:
         return _FUSED_STEP_CACHE[key]
     from . import refine as refine_mod
@@ -428,7 +460,8 @@ def _fused_shard_step(mesh, with_filter: bool = True):
              + (P("data"),) * 6)     # vr, nr, rep_r, vs, ns, rep_s
 
     def _finish(v, verd, vr, nr, rpr, vs, ns, rps):
-        res, unc = refine_mod._intersects_impl_jnp(vr, nr, vs, ns, rpr, rps)
+        res, unc = refine_mod.map_row_chunks(
+            refine_mod._intersects_impl_jnp, chunk, vr, nr, vs, ns, rpr, rps)
         indec = v & (verd == INDECISIVE)
         hit = (verd == TRUE_HIT) | (indec & res)
         unc = unc & indec
@@ -483,9 +516,8 @@ def distributed_fused_join(R, S, approx_r, approx_s,
     kernel evaluates all three joins at once. Returns
     (pairs [K,2] int64, counts dict).
     """
-    from .mbr_join import _pad_rows_pow2, _prepare, candidate_rows
+    from .mbr_join import _prepare, candidate_rows
     from . import refine as refine_mod
-    from jax.experimental import enable_x64
 
     empty = np.zeros((0, 2), np.int64)
     zero = {"mbr_pairs": 0, "true_neg": 0, "true_hit": 0, "indecisive": 0}
@@ -498,9 +530,9 @@ def distributed_fused_join(R, S, approx_r, approx_s,
         return empty, zero
     mesh = mesh or make_join_mesh()
     n_dev = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
-    (mbrs_r, lo_r), _ = _pad_rows_pow2([mbrs_r, lo_r])
-    (mbrs_s, lo_s), _ = _pad_rows_pow2([mbrs_s, lo_s])
-    (pri, psi, pox, poy, vrow), n = _pad_rows_pow2(
+    (mbrs_r, lo_r), _ = pad_rows_pow2([mbrs_r, lo_r])
+    (mbrs_s, lo_s), _ = pad_rows_pow2([mbrs_s, lo_s])
+    (pri, psi, pox, poy, vrow), n = pad_rows_pow2(
         [ri, si, own_x, own_y, np.ones(len(ri), bool)], multiple=n_dev)
     frame = np.stack([pri, psi], axis=1)
     with_filter = not (plan is not None
@@ -516,8 +548,9 @@ def distributed_fused_join(R, S, approx_r, approx_s,
     rpr = refine_mod._reps(R, pri)
     rps = refine_mod._reps(S, psi)
 
-    step = _fused_shard_step(mesh, with_filter)
-    with enable_x64():
+    step = _fused_shard_step(mesh, with_filter, refine_mod.device_chunk_rows(
+        vr.shape[1], vs.shape[1]))
+    with jax.enable_x64(True):
         head = [jnp.asarray(a) for a in (mbrs_r, mbrs_s, lo_r, lo_s,
                                          pri, psi, pox, poy, vrow)]
         tail = [jnp.asarray(a) for a in (vr, nr, rpr, vs, ns, rps)]
@@ -527,6 +560,7 @@ def distributed_fused_join(R, S, approx_r, approx_s,
             verd, hit, unc, counts = step(*head, *tail)
     verd, hit, unc, counts = to_host(verd, hit, unc, counts)
     hit, unc = hit[:n].copy(), unc[:n]
+    note_routed(refine_mod._ESCALATED, np.count_nonzero(unc))
     if unc.any():          # sanctioned f64 escalation of guard-band rows
         esc = frame[:n][unc]
         hit[unc] = (verd[:n][unc] == TRUE_HIT) | refine_mod.refine(

@@ -24,6 +24,7 @@ from typing import Callable
 import numpy as np
 
 from ..core.join import INDECISIVE, TRUE_HIT, TRUE_NEG
+from ..kernels import note_routed, pad_rows_pow2
 
 __all__ = [
     "PIPELINE_MODES", "check_pipeline_mode", "to_host",
@@ -190,8 +191,9 @@ def build_stage_plan(plan, predicate: str) -> StagePlan:
         from ..kernels.compact import compact_mask
         cb = "pallas" if plan.refine_backend == "pallas" else "jnp"
         perm, count = compact_mask(cs.status == INDECISIVE, backend=cb)
-        ri_dev = jnp.asarray(np.asarray(cs.ri, np.int32))
-        si_dev = jnp.asarray(np.asarray(cs.si, np.int32))
+        (ri32, si32), _ = pad_rows_pow2([np.asarray(cs.ri, np.int32),
+                                         np.asarray(cs.si, np.int32)])
+        ri_dev, si_dev = jnp.asarray(ri32), jnp.asarray(si32)
         res, unc, perm_p = RF.fused_refine_lanes(
             plan.R, plan.S, ri_dev, si_dev, perm, count, predicate)
         N = len(cs)
@@ -234,6 +236,7 @@ def execute_fused(plan, predicate: str, stats):
     got = to_host(*lanes)
     status_h, hit_h, unc_h = got[0], np.array(got[1]), got[2]
     valid_h = got[3] if cs.valid is not None else np.ones(len(cs), bool)
+    note_routed(RF._ESCALATED, np.count_nonzero(unc_h))
     if unc_h.any():
         # f64 escalation of the FMA-borderline pairs — identical to the
         # staged jnp refine backend's per-bucket escalation set

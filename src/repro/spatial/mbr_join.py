@@ -44,6 +44,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..kernels import pad_rows_pow2
+
 __all__ = [
     "MBR_BACKENDS", "mbr_join", "mbr_intersect_mask", "adaptive_grid",
     "joint_extent", "bucket_ranges", "expand_buckets", "candidate_rows",
@@ -72,19 +74,6 @@ def _resolve_grid(grid, mbrs_r, mbrs_s, extent) -> int:
         raise ValueError(f"mbr grid must be >= 1 or None (adaptive), "
                          f"got {grid!r}")
     return int(grid)
-
-
-def _pad_rows_pow2(xs: list[np.ndarray], multiple: int = 1
-                   ) -> tuple[list[np.ndarray], int]:
-    """Zero-pad equal-length arrays (along axis 0) to the next power of two
-    (then up to ``multiple``) so jitted consumers recompile logarithmically
-    in the row count; returns (padded arrays, original length)."""
-    n = len(xs[0])
-    p2 = 1 << int(np.ceil(np.log2(max(n, 1))))
-    pad = max(multiple, ((p2 + multiple - 1) // multiple) * multiple)
-    return [x if len(x) == pad else
-            np.concatenate([x, np.zeros((pad - n,) + x.shape[1:], x.dtype)])
-            for x in xs], n
 
 
 def _prepare(mbrs_r: np.ndarray, mbrs_s: np.ndarray, grid: int | None):
@@ -303,7 +292,6 @@ def pair_mask_lane_jnp(mbrs_r, mbrs_s, lo_r, lo_s, ri, si, own_x, own_y):
     global _JNP_MASK
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     if _JNP_MASK is None:
         def mask(mr, ms, lor, los, ri, si, ox, oy, valid):
@@ -314,11 +302,11 @@ def pair_mask_lane_jnp(mbrs_r, mbrs_s, lo_r, lo_s, ri, si, own_x, own_y):
     # the replicated tables pad too: their exact shapes would otherwise
     # retrigger a compile for every distinct dataset size (padded table
     # rows are only gathered by padded candidate rows, masked by `valid`)
-    (mbrs_r, lo_r), _ = _pad_rows_pow2([mbrs_r, lo_r])
-    (mbrs_s, lo_s), _ = _pad_rows_pow2([mbrs_s, lo_s])
-    (ri, si, own_x, own_y, valid), n = _pad_rows_pow2(
+    (mbrs_r, lo_r), _ = pad_rows_pow2([mbrs_r, lo_r])
+    (mbrs_s, lo_s), _ = pad_rows_pow2([mbrs_s, lo_s])
+    (ri, si, own_x, own_y, valid), n = pad_rows_pow2(
         [ri, si, own_x, own_y, np.ones(len(ri), bool)])
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _JNP_MASK(mbrs_r, mbrs_s, lo_r, lo_s, ri, si,
                         own_x, own_y, valid)
     return out, n

@@ -28,6 +28,7 @@ import numpy as np
 from ..core.join import (INDECISIVE, TRUE_HIT, TRUE_NEG,
                          check_filter_backend)
 from ..core.rasterize import Extent, GLOBAL_EXTENT
+from ..kernels import count_routed
 from . import refine
 from .filters import Approximation, IntermediateFilter, get_filter
 from .fused import PIPELINE_MODES, check_pipeline_mode, execute_fused
@@ -373,17 +374,21 @@ class JoinPlan:
         stats.approx_bytes = (self.approx_r.size_bytes()
                               + self.approx_s.size_bytes())
 
+        with count_routed() as routed:
+            results, stats = self._execute(predicate, stats)
+        stats.extra["routed"] = routed
+        self.last_stats = stats
+        return results, stats
+
+    def _execute(self, predicate: str, stats: JoinStats):
         if self.pipeline_mode == "fused":
-            results, stats = execute_fused(self, predicate, stats)
-            self.last_stats = stats
-            return results, stats
+            return execute_fused(self, predicate, stats)
 
         t0 = time.perf_counter()
         pairs = self.candidates(predicate)
         stats.t_mbr = time.perf_counter() - t0
         stats.n_candidates = len(pairs)
         if len(pairs) == 0:
-            self.last_stats = stats
             return np.zeros((0, 2), np.int64), stats
 
         t0 = time.perf_counter()
@@ -401,5 +406,4 @@ class JoinPlan:
         results = np.concatenate([pairs[verdicts == TRUE_HIT], indec[ref]],
                                  axis=0)
         stats.n_results = len(results)
-        self.last_stats = stats
         return results, stats

@@ -39,18 +39,24 @@ import numpy as np
 
 from ..core import geometry
 from ..core.geometry import polygon_edges, segments_intersect, size_buckets
+from ..kernels import note_routed
 
 __all__ = [
     "REFINE_BACKENDS", "refine", "refine_pair",
     "refine_pairs", "refine_within_pairs", "refine_line_poly_pairs",
     "refine_pairs_seq", "refine_within_pairs_seq",
-    "refine_line_poly_pairs_seq", "iter_pair_chunks",
+    "refine_line_poly_pairs_seq", "device_chunk_rows", "map_row_chunks",
 ]
 
 REFINE_BACKENDS = ("numpy", "jnp", "pallas", "sequential")
 
 #: bound on the padded [N, Er, Es] orientation working set per bucket chunk
 _CHUNK_ELEMS = 1 << 20
+#: routed-row counter of guard-band pairs re-checked at host f64
+_ESCALATED = "refine_escalated_rows_host"
+#: bound on the padded [C, Er, Es] orientation tile of one device chunk
+#: (fused and mesh refinement)
+_DEVICE_CHUNK_ELEMS = 1 << 25
 
 
 def _check_backend(backend: str) -> None:
@@ -444,6 +450,32 @@ def _line_impl_jnp(vl, nl, vs, ns):
     return crossed | head_in[:, 0], unc & ~definite_true
 
 
+def device_chunk_rows(Va: int, Vb: int) -> int:
+    """Rows per device refinement chunk: the power of two that keeps the
+    padded [C, Va, Vb] orientation tile within ``_DEVICE_CHUNK_ELEMS``."""
+    by_mem = max(8, _DEVICE_CHUNK_ELEMS // max(1, Va * Vb))
+    return 1 << int(np.floor(np.log2(by_mem)))
+
+
+def map_row_chunks(body, C: int, *rows):
+    """Apply a batched device core (rows -> per-row outputs) to [N, ...]
+    operands ``C`` rows at a time with ``lax.map``: the [C, Er, Es] working
+    set stays bounded whatever N is, and the body compiles once. Traced
+    inside a jit or shard_map step."""
+    import jax
+    import jax.numpy as jnp
+    N = rows[0].shape[0]
+    if N <= C:
+        return body(*rows)
+    m = -(-N // C)
+    pad = m * C - N
+    chunks = tuple(
+        jnp.pad(r, [(0, pad)] + [(0, 0)] * (r.ndim - 1)).reshape(
+            (m, C) + r.shape[1:]) for r in rows)
+    outs = jax.lax.map(lambda xs: body(*xs), chunks)
+    return tuple(o.reshape((m * C,) + o.shape[2:])[:N] for o in outs)
+
+
 _JNP_REFINE_JIT: dict | None = None
 
 
@@ -451,8 +483,7 @@ def _refine_jnp(kind: str, *arrays) -> tuple[np.ndarray, np.ndarray]:
     """Run a jit'd device core; returns (verdicts, uncertain) as numpy."""
     global _JNP_REFINE_JIT
     import jax
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         if _JNP_REFINE_JIT is None:
             _JNP_REFINE_JIT = {
                 "intersects": jax.jit(_intersects_impl_jnp),
@@ -468,12 +499,21 @@ def _refine_jnp(kind: str, *arrays) -> tuple[np.ndarray, np.ndarray]:
 # ---------------------------------------------------------------------------
 
 def _pallas_sweep(a0, a1, am, b0, b1, bm):
-    import jax
+    """f32 device sweep of [N, E, 2] f64 edge sets -> (hit, unc) [N].
+
+    Each row is translated (in f64) to its first A vertex before the f32
+    cast: orientations are translation-invariant, and a pair's edges then
+    sit within its common neighbourhood instead of ~1 from the origin, so
+    the cast keeps ~1e3x more relative precision and the guard band's
+    magnitude term shrinks with it — far fewer pairs come back uncertain.
+    """
+    from ..kernels import interpret_mode, pad_rows_pow2
     from ..kernels.refine import batch_edges_intersect
-    interpret = jax.default_backend() != "tpu"
-    hit, unc = batch_edges_intersect(a0, a1, am, b0, b1, bm,
-                                     interpret=interpret)
-    return np.asarray(hit), np.asarray(unc)
+    origin = a0[:, :1, :]
+    arrays, n = pad_rows_pow2([a0 - origin, a1 - origin, am,
+                               b0 - origin, b1 - origin, bm])
+    hit, unc = batch_edges_intersect(*arrays, interpret=interpret_mode())
+    return np.asarray(hit)[:n], np.asarray(unc)[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -490,22 +530,6 @@ def _bucketed(nvr: np.ndarray, nvs: np.ndarray, fn) -> np.ndarray:
         Vb = int(nvs[sel].max())
         out[sel] = fn(sel, Va, Vb)
     return out
-
-
-def iter_pair_chunks(R, S, pairs: np.ndarray):
-    """Yield (sel, p, vr, nr, vs, ns) vertex-count-bucketed pair chunks —
-    the one bucketing contract shared by the host drivers here and the
-    sharded driver in :mod:`repro.spatial.distributed`."""
-    pairs = np.asarray(pairs, np.int64).reshape(-1, 2)
-    nvr = R.nverts[pairs[:, 0]]
-    nvs = S.nverts[pairs[:, 1]]
-    sizes = np.maximum(nvr, 1) * np.maximum(nvs, 1)
-    for sel in size_buckets(sizes, _CHUNK_ELEMS):
-        p = pairs[sel]
-        Va = int(nvr[sel].max())
-        Vb = int(nvs[sel].max())
-        yield (sel, p, R.verts[:, :Va][p[:, 0]], nvr[sel],
-               S.verts[:, :Vb][p[:, 1]], nvs[sel])
 
 
 def refine_pairs(R, S, pairs: np.ndarray, use_cmbr: bool = True,
@@ -531,6 +555,7 @@ def refine_pairs(R, S, pairs: np.ndarray, use_cmbr: bool = True,
         if backend == "jnp":
             res, unc = _refine_jnp("intersects", vr, nr, vs, ns,
                                    rep_r[sel], rep_s[sel])
+            note_routed(_ESCALATED, np.count_nonzero(unc))
             if unc.any():   # borderline signs: re-run on host (strict IEEE)
                 res[unc] = _intersects_batch_np(
                     vr[unc], nr[unc], vs[unc], ns[unc],
@@ -556,6 +581,7 @@ def _refine_pallas_intersects(R, S, p, vr, nr, vs, ns, rep_r, rep_s,
         ams = am & _cmbr_mask(R.mbrs[p[:, 0]], S.mbrs[p[:, 1]], a0, a1)
         bms = bm & _cmbr_mask(R.mbrs[p[:, 0]], S.mbrs[p[:, 1]], b0, b1)
     hit, unc = _pallas_sweep(a0, a1, ams, b0, b1, bms)
+    note_routed(_ESCALATED, np.count_nonzero(unc))
     out = hit & ~unc
     # no definite crossing: containment via host closed-PiP of the reps
     rest = ~hit & ~unc
@@ -592,6 +618,7 @@ def refine_within_pairs(R, S, pairs: np.ndarray,
         nr, ns = nvr[sel], nvs[sel]
         if backend == "jnp":
             res, unc = _refine_jnp("within", vr, nr, vs, ns)
+            note_routed(_ESCALATED, np.count_nonzero(unc))
             if unc.any():
                 res[unc] = _within_batch_np(
                     vr[unc], nr[unc], vs[unc], ns[unc],
@@ -601,6 +628,7 @@ def refine_within_pairs(R, S, pairs: np.ndarray,
             a0, a1, am = polygon_edges(vr, nr)
             b0, b1, bm = polygon_edges(vs, ns)
             hit, unc = _pallas_sweep(a0, a1, am, b0, b1, bm)
+            note_routed(_ESCALATED, np.count_nonzero(unc))
             out = np.zeros(len(p), bool)       # definite crossing: not within
             todo = ~hit | unc
             if todo.any():
@@ -634,6 +662,7 @@ def refine_line_poly_pairs(L, S, pairs: np.ndarray,
         nl, ns = nvl[sel], nvs[sel]
         if backend == "jnp":
             res, unc = _refine_jnp("line", vl, nl, vs, ns)
+            note_routed(_ESCALATED, np.count_nonzero(unc))
             if unc.any():
                 res[unc] = _line_batch_np(
                     vl[unc], nl[unc], vs[unc], ns[unc],
@@ -643,6 +672,7 @@ def refine_line_poly_pairs(L, S, pairs: np.ndarray,
             a0, a1, am = _chain_edges(vl, nl)
             b0, b1, bm = polygon_edges(vs, ns)
             hit, unc = _pallas_sweep(a0, a1, am, b0, b1, bm)
+            note_routed(_ESCALATED, np.count_nonzero(unc))
             out = hit & ~unc
             rest = ~hit & ~unc
             if rest.any():
@@ -690,13 +720,13 @@ def device_geometry(D, kind: str = "polygon") -> dict:
     per query. The cache keys on the identity of the ``verts`` array —
     incremental dataset patches swap the array and naturally invalidate.
     """
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     key = (id(D.verts), kind)
     cached = getattr(D, "_device_geom", None)
     if cached is not None and cached[0] == key:
         return cached[1]
-    with enable_x64():
+    with jax.enable_x64(True):
         geom = {
             "verts": jnp.asarray(np.asarray(D.verts, np.float64)),
             "nverts": jnp.asarray(np.asarray(D.nverts, np.int32)),
@@ -712,18 +742,16 @@ def device_geometry(D, kind: str = "polygon") -> dict:
 
 
 _FUSED_REFINE_FNS: dict = {}
-#: unroll bound for the chunked packed-prefix loop (compile-time lever)
-_MAX_REFINE_CHUNKS = 32
 
 
 def _fused_refine_fn(kind: str, C: int):
     """jit'd chunked refinement of a front-packed pair prefix.
 
-    The packed frame is walked in static chunks of ``C``; a chunk whose
-    start lies past the device survivor count is skipped with
-    ``jax.lax.cond`` — XLA executes only the taken branch, so the work
-    scales with the (data-dependent) survivor count without the count ever
-    visiting the host.
+    The packed frame is walked in chunks of ``C`` by a ``lax.while_loop``
+    that stops at the first chunk past the device survivor count — the
+    work scales with the (data-dependent) survivor count without the count
+    ever visiting the host, and the refinement body is compiled once, not
+    once per chunk.
     """
     import jax
     import jax.numpy as jnp
@@ -734,32 +762,31 @@ def _fused_refine_fn(kind: str, C: int):
     def run(vr_all, nr_all, rep_r, vs_all, ns_all, rep_s, ri, si,
             perm, count):
         Np = perm.shape[0]
-        res = jnp.zeros(Np, bool)
-        unc = jnp.zeros(Np, bool)
-        for c0 in range(0, Np, C):
-            idx = perm[c0:c0 + C]
-            take = (c0 + jnp.arange(C)) < count
+        lane = jnp.arange(C, dtype=jnp.int32)
 
-            def live(_):
-                rr = ri[idx]
-                ss = si[idx]
-                vr, nr = vr_all[rr], nr_all[rr]
-                vs, ns = vs_all[ss], ns_all[ss]
-                if kind == "intersects":
-                    v, u = _intersects_impl_jnp(vr, nr, vs, ns,
-                                                rep_r[rr], rep_s[ss])
-                elif kind == "within":
-                    v, u = _within_impl_jnp(vr, nr, vs, ns)
-                else:
-                    v, u = _line_impl_jnp(vr, nr, vs, ns)
-                return v & take, u & take
+        def chunk(state):
+            c0, res, unc = state
+            idx = jax.lax.dynamic_slice(perm, (c0,), (C,))
+            take = (c0 + lane) < count
+            rr = ri[idx]
+            ss = si[idx]
+            vr, nr = vr_all[rr], nr_all[rr]
+            vs, ns = vs_all[ss], ns_all[ss]
+            if kind == "intersects":
+                v, u = _intersects_impl_jnp(vr, nr, vs, ns,
+                                            rep_r[rr], rep_s[ss])
+            elif kind == "within":
+                v, u = _within_impl_jnp(vr, nr, vs, ns)
+            else:
+                v, u = _line_impl_jnp(vr, nr, vs, ns)
+            res = jax.lax.dynamic_update_slice(res, v & take, (c0,))
+            unc = jax.lax.dynamic_update_slice(unc, u & take, (c0,))
+            return c0 + C, res, unc
 
-            def dead(_):
-                return jnp.zeros(C, bool), jnp.zeros(C, bool)
-
-            v, u = jax.lax.cond(c0 < count, live, dead, 0)
-            res = res.at[c0:c0 + C].set(v)
-            unc = unc.at[c0:c0 + C].set(u)
+        state = (jnp.zeros((), jnp.int32), jnp.zeros(Np, bool),
+                 jnp.zeros(Np, bool))
+        _, res, unc = jax.lax.while_loop(lambda st: st[0] < count, chunk,
+                                         state)
         return res, unc
 
     _FUSED_REFINE_FNS[(kind, C)] = jax.jit(run)
@@ -771,15 +798,16 @@ def fused_refine_lanes(R, S, ri_dev, si_dev, perm, count,
     """Device (res, unc) lanes over a front-packed indecisive prefix.
 
     ``perm``/``count`` come from ``kernels.compact.compact_mask`` over the
-    INDECISIVE status lane; ``ri_dev``/``si_dev`` are the device pair frame.
+    INDECISIVE status lane; ``ri_dev``/``si_dev`` are the device pair frame
+    (at least ``len(perm)`` rows; callers pad it to a power of two).
     Returns [Np] bool lanes in the *packed* frame (``Np`` = ``len(perm)``
     padded up to the chunk size, padding entries False); scatter back
     through ``perm``. ``unc`` marks FMA-borderline pairs for the single
     end-of-chain host escalation — identical to the staged jnp backend's
     per-bucket escalation set.
     """
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     kind = {"intersects": "intersects", "selection": "intersects",
             "within": "within", "linestring": "line"}[predicate]
@@ -788,18 +816,18 @@ def fused_refine_lanes(R, S, ri_dev, si_dev, perm, count,
     N = perm.shape[0]
     if N == 0:
         return jnp.zeros(0, bool), jnp.zeros(0, bool), perm
-    # chunk size: bounded [C, Er, Es] tile, bounded unroll
+    # chunk size: a bounded [C, Er, Es] tile; the packed frame pads to a
+    # power of two (a multiple of C), so the program compiles once per
+    # size class of the frame, not per frame length
     Va = int(np.asarray(R.nverts).max(initial=1))
     Vb = int(np.asarray(S.nverts).max(initial=1))
-    by_mem = max(8, _CHUNK_ELEMS // max(1, Va * Vb))
-    by_unroll = -(-N // _MAX_REFINE_CHUNKS)
-    C = 1 << int(np.ceil(np.log2(max(by_mem, by_unroll, 1))))
-    Np = -(-N // C) * C
+    Np = 1 << int(np.ceil(np.log2(max(N, 8))))
+    C = min(Np, device_chunk_rows(Va, Vb))
     # pad the permutation with out-of-frame indices: the scatter back into
     # candidate-frame lanes drops them (mode='drop')
     perm_p = jnp.concatenate(
         [perm, jnp.full(Np - N, N, jnp.int32)]) if Np != N else perm
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _fused_refine_fn(kind, C)
         res, unc = fn(geom_r["verts"], geom_r["nverts"],
                       geom_r.get("reps"), geom_s["verts"],

@@ -234,11 +234,11 @@ def test_fp002_true_positive(tmp_path):
 
 def test_fp002_true_negative_scoped_context(tmp_path):
     code = """
+        import jax
         import numpy as np
-        from jax.experimental import enable_x64
 
         def compute(x):
-            with enable_x64():
+            with jax.enable_x64(True):
                 return np.asarray(x)
     """
     assert _run(tmp_path, "src/repro/core/setup.py", code,

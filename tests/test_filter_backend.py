@@ -123,6 +123,39 @@ def test_pallas_trichotomy_matches_reference():
     np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("predicate", ["intersects", "within"])
+def test_pallas_wide_rows_are_counted(predicate):
+    """Rows wider than the kernel tile take the host pass — verdicts
+    unchanged — and every such row is counted, not hidden."""
+    from repro.kernels import count_routed
+    rng = np.random.default_rng(13)
+    sr = _random_store(rng, 4, p_empty=0.0, max_len=400, max_id=2**16)
+    ss = _random_store(rng, 4, p_empty=0.0, max_len=6, max_id=2**16)
+    pairs = _all_pairs(len(sr), len(ss))
+    batch = (join.april_filter_batch if predicate == "intersects"
+             else join.within_filter_batch)
+    want = batch(sr, ss, pairs, backend="numpy")
+    widest = np.maximum(np.diff(sr.a_off)[pairs[:, 0]],
+                        np.diff(ss.a_off)[pairs[:, 1]])
+    if predicate == "intersects":
+        widest = np.maximum.reduce([widest, np.diff(sr.f_off)[pairs[:, 0]],
+                                    np.diff(ss.f_off)[pairs[:, 1]]])
+    n_wide = int(np.sum(widest > join._PALLAS_MAX_WIDTH))
+    assert n_wide > 0
+    with count_routed() as routed:
+        got = batch(sr, ss, pairs, backend="pallas")
+    np.testing.assert_array_equal(got, want)
+    assert routed["filter_wide_rows_host"] == n_wide
+    # a JoinPlan run reports its counts in the stats
+    R = make_dataset("T1", seed=3, count=30)
+    S = make_dataset("T2", seed=4, count=30)
+    _, st = JoinPlan(R, S, n_order=N_ORDER, filter_backend="pallas",
+                     refine_backend="pallas").execute(predicate)
+    assert set(st.extra["routed"]) == {"filter_wide_rows_host",
+                                       "compact_long_lane_rows_jnp",
+                                       "refine_escalated_rows_host"}
+
+
 def test_compressed_store_bounded_decode_matches():
     """APRIL-C staged bounded decode == sequential streaming reference on
     every predicate (polygon reading), on every batched backend."""

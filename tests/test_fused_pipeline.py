@@ -177,7 +177,7 @@ def test_compact_mask_matches_oracle(backend):
 
     for mask in _masks():
         m = jnp.asarray(mask)
-        perm, count = compact_mask(m, backend=backend, interpret=True)
+        perm, count = compact_mask(m, backend=backend)
         perm_ref, count_ref = compact_mask_ref(m)
         assert int(count) == int(count_ref) == int(mask.sum()), len(mask)
         assert np.array_equal(np.asarray(perm), np.asarray(perm_ref)), \

@@ -253,6 +253,35 @@ def test_distributed_refine_matches_host(rs, poly_pairs):
     np.testing.assert_array_equal(w_got, w_want)
 
 
+@pytest.mark.parametrize("predicate", ["intersects", "within"])
+def test_distributed_refine_width_classes(rs, poly_pairs, monkeypatch,
+                                          predicate):
+    """With chunks small enough for the width classes to fill them, each
+    class gathers its own vertex widths — narrower than the dataset's —
+    and the verdicts stay identical to the host."""
+    from repro.spatial import distributed
+    R, S = rs
+    monkeypatch.setattr(refine, "_DEVICE_CHUNK_ELEMS", 1 << 12)
+    widths = set()
+    make_step = distributed._refine_shard_step
+
+    def recording_step(*a):
+        step = make_step(*a)
+
+        def run(vr, nr, vs, ns, *rest):
+            widths.add((vr.shape[1], vs.shape[1]))
+            return step(vr, nr, vs, ns, *rest)
+        return run
+
+    monkeypatch.setattr(distributed, "_refine_shard_step", recording_step)
+    got, _ = distributed_refine(R, S, poly_pairs, predicate=predicate)
+    want = refine.refine(R, S, poly_pairs, predicate=predicate)
+    np.testing.assert_array_equal(got, want)
+    full = (R.verts.shape[1], S.verts.shape[1])
+    assert len(widths) > 1 and full in widths
+    assert all(a <= full[0] and b <= full[1] for a, b in widths)
+
+
 def test_distributed_refine_linestring(rs):
     _, S = rs
     L = make_linestrings(seed=34, count=60)

@@ -38,3 +38,23 @@ def test_launcher_adaptive_plan_matches_pipeline():
     S = make_dataset("T2", seed=1, count=90)
     ref, _ = spatial_intersection_join(R, S, method="none")
     assert _pairs_set(res) == _pairs_set(ref)
+
+
+def test_routed_counts_nest_into_the_enclosing_block():
+    from repro.kernels import count_routed, note_routed
+    with count_routed() as outer:
+        note_routed("filter_wide_rows_host", 2)
+        with count_routed() as inner:
+            note_routed("refine_escalated_rows_host", 3)
+        assert inner["refine_escalated_rows_host"] == 3
+    assert outer["filter_wide_rows_host"] == 2
+    assert outer["refine_escalated_rows_host"] == 3
+
+
+def test_launcher_reports_routed_rows():
+    # the mesh paths' guard-band escalations reach the launcher's counts
+    from repro.kernels import ROUTED_KEYS
+    _, totals = run_join("T1", "T2", n_order=7, parts=2, seed=0,
+                         count_r=60, count_s=90, pipeline_mode="fused")
+    assert set(totals["routed"]) == set(ROUTED_KEYS)
+    assert all(v >= 0 for v in totals["routed"].values())

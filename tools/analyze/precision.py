@@ -16,7 +16,7 @@ tests that skip the idiom:
 * **FP002** — ``jax.config.update("jax_enable_x64", ...)`` in library
   code: a process-global precision flip reachable from f32 paths (the
   pallas kernels run f32 by contract).  Use the scoped
-  ``jax.experimental.enable_x64`` context manager instead.
+  ``with jax.enable_x64(True):`` context manager instead.
 """
 from __future__ import annotations
 
@@ -87,7 +87,7 @@ class PrecisionPass(AnalysisPass):
                  "guard-band idiom (FMA contraction can flip near-zero "
                  "signs vs strict IEEE)",
         "FP002": "process-global jax_enable_x64 flip in library code; use "
-                 "the scoped enable_x64() context manager",
+                 "the scoped jax.enable_x64(True) context manager",
     }
 
     _SCOPE = ("src/repro/spatial/", "src/repro/core/", "src/repro/kernels/")
@@ -144,5 +144,5 @@ class PrecisionPass(AnalysisPass):
                     "process-global jax_enable_x64 update in library code "
                     "changes precision for every caller (including f32 "
                     "pallas paths); scope it with "
-                    "`with jax.experimental.enable_x64():`"))
+                    "`with jax.enable_x64(True):`"))
         return out
