@@ -30,6 +30,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..kernels import to_device
+from ..runtime.trace import count, span
 from .hilbert import u32_to_biased_i32
 from .rasterize import size_buckets
 
@@ -479,7 +481,7 @@ class IntervalLists:
                                                              np.int32)
             l = self.lasts if len(self.lasts) else np.full(1, I32_MAX,
                                                            np.int32)
-            self._device = (jnp.asarray(s), jnp.asarray(l))
+            self._device = (to_device(s), to_device(l))
         return self._device
 
     # -- incremental maintenance (row splices, DESIGN.md §10) ---------------
@@ -849,15 +851,15 @@ def _fused_status_fn(kind: str):
     return _FUSED_STATUS_FNS[kind]
 
 
-def _bucket_args(L: IntervalLists, idx, cnt, sel, Bp: int):
-    """Per-bucket device args for one list side: the resident flat endpoint
-    arrays plus padded [Bp] row offsets/counts (padding rows count 0)."""
+def _bucket_rows(L: IntervalLists, idx, cnt, sel, Bp: int):
+    """One list side's padded [Bp] row offsets/counts for a bucket, on the
+    host (padding rows count 0); the program reads them against the
+    resident flat endpoint arrays ``L.device()``."""
     lo = np.zeros(Bp, np.int64)
     ct = np.zeros(Bp, np.int32)
     lo[:len(sel)] = L.off[idx[sel]]
     ct[:len(sel)] = cnt[sel]
-    fs, fl = L.device()
-    return fs, fl, jnp.asarray(lo), jnp.asarray(ct)
+    return lo, ct
 
 
 def fused_status_rows(predicate: str, Xa: IntervalLists,
@@ -872,6 +874,16 @@ def fused_status_rows(predicate: str, Xa: IntervalLists,
     on either side stay TRUE_NEG, like the staged paths). ``predicate`` is
     'intersects' (Xf required), 'within' (Xf unused) or 'linestring' (Xa is
     the chain's unit-cell lists). Verdict-identical to the staged drivers.
+
+    Each bucket program gathers ``Bp`` padded rows of every list at its
+    power-of-two width ``W``: the trace block counts the programs
+    (``filter_buckets``), the live rows (``filter_rows``), the padded rows
+    (``filter_padded_rows``) and the gathered bytes (``filter_gather_bytes``,
+    ``Bp * sum(W) * 8``: a start and a last, int32 each, per slot). Each
+    bucket's span ``repro.filter.bucket`` holds ``repro.filter.args``, its
+    host-only argument build, and ``repro.filter.dispatch``, the uploads,
+    the program call and the lane scatter, which can block while the
+    device is busy.
     """
     ri = np.asarray(ri, np.int64)
     si = np.asarray(si, np.int64)
@@ -879,34 +891,53 @@ def fused_status_rows(predicate: str, Xa: IntervalLists,
     lane = jnp.zeros(N, jnp.int8)               # TRUE_NEG
     if N == 0:
         return lane
-    ca_r = Xa.counts(ri)
-    ca_s = Ya.counts(si)
-    cf_s = Yf.counts(si)
-    live = (ca_r > 0) & (ca_s > 0)
-    if predicate == "intersects":
-        cf_r = Xf.counts(ri)
-        widths = np.maximum.reduce([ca_r, cf_r, ca_s, cf_s])
-    else:
-        widths = np.maximum.reduce([ca_r, ca_s, cf_s])
-    fn = _fused_status_fn(predicate)
-    for sel in size_buckets(np.where(live, np.maximum(widths, 1), 0),
-                            _BUCKET_CHUNK):
-        Bp = _pow2(len(sel))
-        args = _bucket_args(Xa, ri, ca_r, sel, Bp)
-        kw = {}
+    with span("repro.filter.plan"):
+        ca_r = Xa.counts(ri)
+        ca_s = Ya.counts(si)
+        cf_s = Yf.counts(si)
+        live = (ca_r > 0) & (ca_s > 0)
         if predicate == "intersects":
-            args += _bucket_args(Xf, ri, cf_r, sel, Bp)
-            kw["Wxa"] = _pow2(ca_r[sel].max())
-            kw["Wxf"] = _pow2(max(1, cf_r[sel].max()))
+            cf_r = Xf.counts(ri)
+            widths = np.maximum.reduce([ca_r, cf_r, ca_s, cf_s])
         else:
-            key = "Wc" if predicate == "linestring" else "Wxa"
-            kw[key] = _pow2(ca_r[sel].max())
-        args += _bucket_args(Ya, si, ca_s, sel, Bp)
-        args += _bucket_args(Yf, si, cf_s, sel, Bp)
-        kw["Wya"] = _pow2(ca_s[sel].max())
-        kw["Wyf"] = _pow2(max(1, cf_s[sel].max()))
-        st = fn(*args, **kw)
-        lane = lane.at[jnp.asarray(sel)].set(st[:len(sel)])
+            widths = np.maximum.reduce([ca_r, ca_s, cf_s])
+        # lazy: each width class's rows are found only when the loop
+        # reaches it, so that host work overlaps the buckets already on
+        # the device
+        buckets = size_buckets(np.where(live, np.maximum(widths, 1), 0),
+                               _BUCKET_CHUNK)
+    fn = _fused_status_fn(predicate)
+    while True:
+        with span("repro.filter.bucket"):
+            with span("repro.filter.args"):
+                sel = next(buckets, None)
+                if sel is None:
+                    break
+                Bp = _pow2(len(sel))
+                sides = [(Xa, ri, ca_r)]
+                kw = {}
+                if predicate == "intersects":
+                    sides.append((Xf, ri, cf_r))
+                    kw["Wxa"] = _pow2(ca_r[sel].max())
+                    kw["Wxf"] = _pow2(max(1, cf_r[sel].max()))
+                else:
+                    key = "Wc" if predicate == "linestring" else "Wxa"
+                    kw[key] = _pow2(ca_r[sel].max())
+                sides += [(Ya, si, ca_s), (Yf, si, cf_s)]
+                kw["Wya"] = _pow2(ca_s[sel].max())
+                kw["Wyf"] = _pow2(max(1, cf_s[sel].max()))
+                rows = [(L, *_bucket_rows(L, idx, cnt, sel, Bp))
+                        for L, idx, cnt in sides]
+            with span("repro.filter.dispatch"):
+                args = ()
+                for L, lo, ct in rows:
+                    args += (*L.device(), to_device(lo), to_device(ct))
+                st = fn(*args, **kw)
+                lane = lane.at[to_device(sel)].set(st[:len(sel)])
+            count("filter_buckets")
+            count("filter_rows", len(sel))
+            count("filter_padded_rows", Bp)
+            count("filter_gather_bytes", Bp * sum(kw.values()) * 8)
     return lane
 
 

@@ -13,24 +13,18 @@ This module also holds the device-path policy shared by every caller:
 counts* record every row the device path hands elsewhere — interval rows
 wider than a kernel tile admits (to the host), lanes longer than one
 compaction launch (to jnp), and guard-band pairs re-checked at host f64.
-``JoinPlan.execute`` counts each run under :func:`count_routed` and
+The counts live in the trace blocks of :mod:`repro.runtime.trace`,
+re-exported here: ``JoinPlan.execute`` counts each run in one block and
 reports the counts in ``JoinStats.extra["routed"]``, and the launcher's
 ``run_join``/``run_tiled_join`` count and print theirs (mesh paths
-included), so no cut-off is silent.
+included), so no cut-off is silent. Uploads to the device go through
+:func:`to_device`, which counts their bytes in the same block.
 """
-import contextlib
-import contextvars
-
+import jax.numpy as jnp
 import numpy as np
 
-#: routed-row count names (see :func:`note_routed`)
-ROUTED_KEYS = ("filter_wide_rows_host", "compact_long_lane_rows_jnp",
-               "refine_escalated_rows_host")
-
-#: the counts of the innermost :func:`count_routed` block of this thread
-#: (or task); None outside any block
-_ROUTED: contextvars.ContextVar = contextvars.ContextVar("routed_rows",
-                                                         default=None)
+from ..runtime.trace import (ROUTED_KEYS, count, count_routed,  # noqa: F401
+                             note_routed)
 
 
 def interpret_mode() -> bool:
@@ -40,6 +34,16 @@ def interpret_mode() -> bool:
     fallback on the chip."""
     import jax
     return jax.devices()[0].platform != "tpu"
+
+
+def to_device(x):
+    """The host -> device upload of one host array: the device copy, its
+    bytes counted as ``h2d_bytes`` in the current trace block. Dtypes
+    follow ``jnp.asarray`` (so call it under ``jax.enable_x64`` where a
+    64-bit array must stay 64-bit)."""
+    out = jnp.asarray(x)
+    count("h2d_bytes", out.nbytes)
+    return out
 
 
 def pad_rows_pow2(xs: list[np.ndarray], multiple: int = 1
@@ -55,29 +59,3 @@ def pad_rows_pow2(xs: list[np.ndarray], multiple: int = 1
     return [x if len(x) == pad else
             np.concatenate([x, np.zeros((pad - n,) + x.shape[1:], x.dtype)])
             for x in xs], n
-
-
-@contextlib.contextmanager
-def count_routed():
-    """Count the rows routed off the device path inside the block; yields
-    the ``{name: rows}`` dict it fills. Blocks nest — an inner block's
-    counts also add to the enclosing block's — and each thread counts its
-    own work."""
-    outer = _ROUTED.get()
-    counts = dict.fromkeys(ROUTED_KEYS, 0)
-    token = _ROUTED.set(counts)
-    try:
-        yield counts
-    finally:
-        _ROUTED.reset(token)
-        if outer is not None:
-            for key, n in counts.items():
-                outer[key] += n
-
-
-def note_routed(key: str, n: int) -> None:
-    """Add ``n`` rows to routed-row count ``key`` of the current
-    :func:`count_routed` block (a no-op outside one)."""
-    counts = _ROUTED.get()
-    if counts is not None and n:
-        counts[key] += int(n)

@@ -9,7 +9,7 @@ traffic trace into it: a mix of ``selection`` / ``window`` /
 ``intersects`` / ``within`` queries whose polygons are drawn from a second
 synthetic layer over the same map, interleaved with ``insert`` / ``delete``
 mutations that exercise the incremental store patches. Reports sustained
-queries/sec, p50/p99 latency with the per-stage device-time breakdown
+queries/sec, p50/p99 latency with the per-stage host-time breakdown
 (``t_mbr``/``t_filter``/``t_refine``/``t_sync``), and cache hit/eviction
 stats; ``--pipeline-mode fused`` routes every micro-batched group through
 the device-resident fused chain (DESIGN.md §12); ``--plan-mode adaptive``

@@ -38,6 +38,7 @@ import numpy as np
 from ...core.join import INDECISIVE
 from ...core.join import FILTER_BACKENDS as _FILTER_BACKENDS
 from ...core.rasterize import Extent, GLOBAL_EXTENT
+from ...kernels import to_device
 
 __all__ = [
     "PREDICATES", "BACKENDS", "FILTER_BACKENDS", "BUILD_BACKENDS",
@@ -150,7 +151,7 @@ class IntermediateFilter(abc.ABC):
             return jnp.zeros(0, jnp.int8)
         verd = self.verdicts(approx_r, approx_s, np.stack([ri, si], axis=1),
                              predicate=predicate, backend=backend, **opts)
-        return jnp.asarray(verd)
+        return to_device(verd)
 
     # -- incremental maintenance (DESIGN.md §10) ----------------------------
     def patch_insert(self, approx: Approximation, dataset_one) -> None:

@@ -17,14 +17,14 @@ references; fused changes *where* stage boundaries live, never verdicts.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Callable
 
 import numpy as np
 
 from ..core.join import INDECISIVE, TRUE_HIT, TRUE_NEG
-from ..kernels import note_routed, pad_rows_pow2
+from ..kernels import note_routed, pad_rows_pow2, to_device
+from ..runtime.trace import count, span
 
 __all__ = [
     "PIPELINE_MODES", "check_pipeline_mode", "to_host",
@@ -51,9 +51,13 @@ def to_host(*vals):
     the lexical choke point the HS001 static pass holds the fused pipeline
     to (staged reference paths route their per-stage pulls through here
     too, so intent stays visible). Returns numpy arrays, one per operand.
+    Counts one ``syncs`` and the gathered ``d2h_bytes`` in the current
+    trace block.
     """
     import jax
     got = jax.device_get(list(vals))  # analyze: ignore[HS001] the one sanctioned sync (DESIGN.md §12)
+    count("syncs")
+    count("d2h_bytes", sum(getattr(g, "nbytes", 0) for g in got))
     return got[0] if len(vals) == 1 else tuple(got)
 
 
@@ -87,8 +91,9 @@ class CandidateSet:
 
 @dataclass
 class Stage:
-    """One link of the chain; ``name`` keys the JoinStats wall-time field
-    (``t_mbr`` / ``t_filter`` / ``t_refine``)."""
+    """One link of the chain; ``name`` keys its span (``repro.<name>``)
+    and the JoinStats host-time field (``t_mbr`` / ``t_filter`` /
+    ``t_refine``)."""
     name: str
     fn: Callable
 
@@ -97,23 +102,20 @@ class StagePlan:
     """An ordered CandidateSet -> CandidateSet chain, dispatched back to
     back with no intermediate host syncs.
 
-    Per-stage wall times record *dispatch* cost only — JAX dispatch is
-    asynchronous, so the device work of the whole chain surfaces in the
-    end-of-chain gather, reported as ``t_sync``.
+    Each stage runs in the span ``repro.<name>``, whose host seconds
+    ``JoinPlan.execute`` reports as ``stats.t_<name>``. They cover host
+    work and *dispatch* only — JAX dispatch is asynchronous, so the device
+    work of the whole chain surfaces in the end-of-chain gather, reported
+    as ``t_sync``.
     """
 
     def __init__(self, stages: list[Stage]):
         self.stages = list(stages)
 
-    def run(self, cs: CandidateSet | None = None, stats=None) -> CandidateSet:
+    def run(self, cs: CandidateSet | None = None) -> CandidateSet:
         for st in self.stages:
-            t0 = time.perf_counter()
-            cs = st.fn(cs)
-            if stats is not None:
-                field = "t_" + st.name
-                setattr(stats, field,
-                        getattr(stats, field, 0.0)
-                        + time.perf_counter() - t0)
+            with span("repro." + st.name):
+                cs = st.fn(cs)
         return cs
 
 
@@ -149,27 +151,33 @@ def build_stage_plan(plan, predicate: str) -> StagePlan:
         from .mbr_join import _prepare, candidate_rows, pair_mask_lane_jnp
         R, S = plan.R, plan.S
         if plan.mbr_index is not None or plan.mbr_backend != "jnp":
-            pairs = plan.candidates(predicate)
+            with span("repro.mbr.frame"):
+                pairs = plan.candidates(predicate)
             if len(pairs) == 0:
                 return _empty_cs()
             return CandidateSet(ri=pairs[:, 0], si=pairs[:, 1])
-        mbrs_r, mbrs_s, k, extent = _prepare(R.mbrs, S.mbrs, plan.mbr_grid)
-        if k == 0:
-            return _empty_cs()
-        ri, si, own_x, own_y, lo_r, lo_s = candidate_rows(
-            mbrs_r, mbrs_s, k, extent)
+        with span("repro.mbr.frame"):
+            mbrs_r, mbrs_s, k, extent = _prepare(R.mbrs, S.mbrs,
+                                                 plan.mbr_grid)
+            if k == 0:
+                return _empty_cs()
+            ri, si, own_x, own_y, lo_r, lo_s = candidate_rows(
+                mbrs_r, mbrs_s, k, extent)
         if len(ri) == 0:
             return _empty_cs()
-        lane, n = pair_mask_lane_jnp(mbrs_r, mbrs_s, lo_r, lo_s,
-                                     ri, si, own_x, own_y)
-        valid = lane[:n]
-        if predicate == "within":
-            # the stricter containment restriction of JoinPlan.candidates,
-            # evaluated on the host MBR tables and folded into the lane
-            mr, ms = mbrs_r[ri], mbrs_s[si]
-            inside = ((mr[:, 0] >= ms[:, 0]) & (mr[:, 1] >= ms[:, 1])
-                      & (mr[:, 2] <= ms[:, 2]) & (mr[:, 3] <= ms[:, 3]))
-            valid = valid & jnp.asarray(inside)
+        with span("repro.mbr.mask"):
+            lane, n = pair_mask_lane_jnp(mbrs_r, mbrs_s, lo_r, lo_s,
+                                         ri, si, own_x, own_y)
+            valid = lane[:n]
+            if predicate == "within":
+                # the stricter containment restriction of
+                # JoinPlan.candidates, evaluated on the host MBR tables
+                # and folded into the lane
+                mr, ms = mbrs_r[ri], mbrs_s[si]
+                inside = ((mr[:, 0] >= ms[:, 0]) & (mr[:, 1] >= ms[:, 1])
+                          & (mr[:, 2] <= ms[:, 2])
+                          & (mr[:, 3] <= ms[:, 3]))
+                valid = valid & to_device(inside)
         return CandidateSet(ri=ri, si=si, valid=valid)
 
     def filter_stage(cs):
@@ -190,16 +198,19 @@ def build_stage_plan(plan, predicate: str) -> StagePlan:
             return cs
         from ..kernels.compact import compact_mask
         cb = "pallas" if plan.refine_backend == "pallas" else "jnp"
-        perm, count = compact_mask(cs.status == INDECISIVE, backend=cb)
-        (ri32, si32), _ = pad_rows_pow2([np.asarray(cs.ri, np.int32),
-                                         np.asarray(cs.si, np.int32)])
-        ri_dev, si_dev = jnp.asarray(ri32), jnp.asarray(si32)
-        res, unc, perm_p = RF.fused_refine_lanes(
-            plan.R, plan.S, ri_dev, si_dev, perm, count, predicate)
-        N = len(cs)
-        hit_ref = jnp.zeros(N, bool).at[perm_p].set(res, mode="drop")
-        cs.hit = (cs.status == TRUE_HIT) | hit_ref
-        cs.unc = jnp.zeros(N, bool).at[perm_p].set(unc, mode="drop")
+        with span("repro.refine.compact"):
+            perm, n_indec = compact_mask(cs.status == INDECISIVE, backend=cb)
+        with span("repro.refine.upload"):
+            (ri32, si32), _ = pad_rows_pow2([np.asarray(cs.ri, np.int32),
+                                             np.asarray(cs.si, np.int32)])
+            ri_dev, si_dev = to_device(ri32), to_device(si32)
+        with span("repro.refine.lanes"):
+            res, unc, perm_p = RF.fused_refine_lanes(
+                plan.R, plan.S, ri_dev, si_dev, perm, n_indec, predicate)
+            N = len(cs)
+            hit_ref = jnp.zeros(N, bool).at[perm_p].set(res, mode="drop")
+            cs.hit = (cs.status == TRUE_HIT) | hit_ref
+            cs.unc = jnp.zeros(N, bool).at[perm_p].set(unc, mode="drop")
         return cs
 
     return StagePlan([Stage("mbr", mbr_stage),
@@ -216,41 +227,43 @@ def execute_fused(plan, predicate: str, stats):
 
     Result rows reproduce the staged ordering exactly: TRUE_HIT pairs in
     frame order, then refined-true INDECISIVE pairs in frame order.
-    ``stats.t_sync`` times the end-of-chain gather plus the f64 host
-    escalation of FMA-borderline pairs (the one permitted round trip);
-    the per-stage times are dispatch-only.
+    Each step runs in a span; ``JoinPlan.execute`` turns their host
+    seconds into the times in ``stats``: the stage times (``t_mbr``/
+    ``t_filter``/``t_refine``) cover host work and dispatch only, and the
+    device work of the chain lands in ``t_sync`` — the end-of-chain gather
+    (``repro.sync.gather``) plus the f64 host escalation of FMA-borderline
+    pairs (``repro.sync.escalate``, the one permitted round trip).
     """
     from . import refine as RF
-    sp = build_stage_plan(plan, predicate)
-    cs = sp.run(stats=stats)
-
-    t0 = time.perf_counter()
+    cs = build_stage_plan(plan, predicate).run()
     if len(cs) == 0:
-        stats.t_sync = time.perf_counter() - t0
         return np.zeros((0, 2), np.int64), stats
-    frame = np.stack([np.asarray(cs.ri, np.int64),
-                      np.asarray(cs.si, np.int64)], axis=1)
+    with span("repro.join.assemble"):
+        frame = np.stack([np.asarray(cs.ri, np.int64),
+                          np.asarray(cs.si, np.int64)], axis=1)
     lanes = (cs.status, cs.hit, cs.unc)
     if cs.valid is not None:
         lanes += (cs.valid,)
-    got = to_host(*lanes)
+    with span("repro.sync.gather"):
+        got = to_host(*lanes)
     status_h, hit_h, unc_h = got[0], np.array(got[1]), got[2]
     valid_h = got[3] if cs.valid is not None else np.ones(len(cs), bool)
     note_routed(RF._ESCALATED, np.count_nonzero(unc_h))
-    if unc_h.any():
-        # f64 escalation of the FMA-borderline pairs — identical to the
-        # staged jnp refine backend's per-bucket escalation set
-        esc = frame[unc_h]
-        hit_h[unc_h] = RF.refine(plan.R, plan.S, esc, predicate=predicate,
-                                 backend="numpy")
-    stats.t_sync = time.perf_counter() - t0
+    with span("repro.sync.escalate"):
+        if unc_h.any():
+            # f64 escalation of the FMA-borderline pairs — identical to the
+            # staged jnp refine backend's per-bucket escalation set
+            esc = frame[unc_h]
+            hit_h[unc_h] = RF.refine(plan.R, plan.S, esc,
+                                     predicate=predicate, backend="numpy")
 
-    stats.n_candidates = int(valid_h.sum())
-    stats.n_true_hits = int(np.sum((status_h == TRUE_HIT) & valid_h))
-    stats.n_true_negs = int(np.sum((status_h == TRUE_NEG) & valid_h))
-    stats.n_indecisive = int(np.sum((status_h == INDECISIVE) & valid_h))
-    indec = status_h == INDECISIVE
-    results = np.concatenate([frame[status_h == TRUE_HIT],
-                              frame[indec & hit_h]], axis=0)
-    stats.n_results = len(results)
+    with span("repro.join.assemble"):
+        stats.n_candidates = int(valid_h.sum())
+        stats.n_true_hits = int(np.sum((status_h == TRUE_HIT) & valid_h))
+        stats.n_true_negs = int(np.sum((status_h == TRUE_NEG) & valid_h))
+        stats.n_indecisive = int(np.sum((status_h == INDECISIVE) & valid_h))
+        indec = status_h == INDECISIVE
+        results = np.concatenate([frame[status_h == TRUE_HIT],
+                                  frame[indec & hit_h]], axis=0)
+        stats.n_results = len(results)
     return results, stats

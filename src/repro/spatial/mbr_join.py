@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..kernels import pad_rows_pow2
+from ..kernels import pad_rows_pow2, to_device
 
 __all__ = [
     "MBR_BACKENDS", "mbr_join", "mbr_intersect_mask", "adaptive_grid",
@@ -307,8 +307,9 @@ def pair_mask_lane_jnp(mbrs_r, mbrs_s, lo_r, lo_s, ri, si, own_x, own_y):
     (ri, si, own_x, own_y, valid), n = pad_rows_pow2(
         [ri, si, own_x, own_y, np.ones(len(ri), bool)])
     with jax.enable_x64(True):
-        out = _JNP_MASK(mbrs_r, mbrs_s, lo_r, lo_s, ri, si,
-                        own_x, own_y, valid)
+        out = _JNP_MASK(*(to_device(a) for a in (mbrs_r, mbrs_s, lo_r, lo_s,
+                                                 ri, si, own_x, own_y,
+                                                 valid)))
     return out, n
 
 

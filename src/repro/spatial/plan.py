@@ -11,9 +11,10 @@ Every execution runs the paper's stages dataset-batched end to end — MBR
 candidate generation (one partitioned grid-hash join, §8) -> intermediate
 filter (one batched ``verdicts`` call, §3) -> refinement of the indecisive
 remainder (one bucketed exact-geometry pass, §7) — and returns
-:class:`JoinStats` with per-stage wall times, the shape of the paper's
-Tables 5/13/16/17 and Fig. 13. Each stage's execution path is a backend
-knob (``mbr_backend`` / ``filter_backend`` / ``refine_backend``, plus
+:class:`JoinStats` with per-stage host times, the shape of the paper's
+Tables 5/13/16/17 and Fig. 13, and the run's trace block (DESIGN.md §12).
+Each stage's execution path is a backend knob (``mbr_backend`` /
+``filter_backend`` / ``refine_backend``, plus
 ``build_opts["build_backend"]`` for construction, §6); backends change
 execution, never results.
 """
@@ -28,7 +29,7 @@ import numpy as np
 from ..core.join import (INDECISIVE, TRUE_HIT, TRUE_NEG,
                          check_filter_backend)
 from ..core.rasterize import Extent, GLOBAL_EXTENT
-from ..kernels import count_routed
+from ..runtime.trace import span, trace_block
 from . import refine
 from .filters import Approximation, IntermediateFilter, get_filter
 from .fused import PIPELINE_MODES, check_pipeline_mode, execute_fused
@@ -61,11 +62,15 @@ class JoinStats:
     #: §14 tiled scale-out only: number of memory-budgeted tiles the run
     #: was packed into (0 = in-memory join, no tiling)
     tiles: int = 0
+    #: host seconds of each stage's span (``repro.mbr`` / ``repro.filter``
+    #: / ``repro.refine``); in fused mode they cover dispatch only, and the
+    #: device work lands in ``t_sync``
     t_mbr: float = 0.0
     t_filter: float = 0.0
     t_refine: float = 0.0
-    #: fused mode only: the end-of-chain gather + f64 escalation (staged
-    #: stage times include their own syncs, so this stays 0.0 there)
+    #: fused mode only: host seconds of the end-of-chain gather + f64
+    #: escalation, which wait for the chain's device work (staged stage
+    #: times include their own syncs, so this stays 0.0 there)
     t_sync: float = 0.0
     t_build: float = 0.0
     #: §14 tiled scale-out only: wall time of the streaming partitioner
@@ -79,8 +84,10 @@ class JoinStats:
         return self.t_mbr + self.t_filter + self.t_refine + self.t_sync
 
     def stage_times(self) -> dict:
-        """Per-stage device-time breakdown (the serving latency report):
-        JSON-safe, round-trips through to_dict/from_dict."""
+        """Per-stage host-time breakdown (the serving latency report):
+        host seconds, not device time — in fused mode the stage times are
+        dispatch only and the device work lands in ``t_sync``. JSON-safe,
+        round-trips through to_dict/from_dict."""
         return {"t_mbr": float(self.t_mbr), "t_filter": float(self.t_filter),
                 "t_refine": float(self.t_refine),
                 "t_sync": float(self.t_sync),
@@ -348,6 +355,11 @@ class JoinPlan:
         For ``selection``, result rows are (data index, query index) — see
         :func:`repro.spatial.pipeline.selection_queries` for the per-query
         grouping wrapper.
+
+        The run is one trace block (:mod:`repro.runtime.trace`) under the
+        span ``repro.join``; ``stats.extra`` reports its routed rows
+        (``routed``), counters (``counters``) and host seconds per span
+        (``spans_s``).
         """
         if predicate == "linestring" and self.r_kind != "line":
             raise ValueError("predicate 'linestring' needs JoinPlan(..., "
@@ -374,9 +386,18 @@ class JoinPlan:
         stats.approx_bytes = (self.approx_r.size_bytes()
                               + self.approx_s.size_bytes())
 
-        with count_routed() as routed:
-            results, stats = self._execute(predicate, stats)
-        stats.extra["routed"] = routed
+        with trace_block() as block:
+            with span("repro.join"):
+                results, stats = self._execute(predicate, stats)
+        spans = block.spans_s
+        stats.t_mbr = spans.get("repro.mbr", 0.0)
+        stats.t_filter = spans.get("repro.filter", 0.0)
+        stats.t_refine = spans.get("repro.refine", 0.0)
+        stats.t_sync = (spans.get("repro.sync.gather", 0.0)
+                        + spans.get("repro.sync.escalate", 0.0))
+        stats.extra["routed"] = block.routed
+        stats.extra["counters"] = block.counters
+        stats.extra["spans_s"] = spans
         self.last_stats = stats
         return results, stats
 
@@ -384,24 +405,21 @@ class JoinPlan:
         if self.pipeline_mode == "fused":
             return execute_fused(self, predicate, stats)
 
-        t0 = time.perf_counter()
-        pairs = self.candidates(predicate)
-        stats.t_mbr = time.perf_counter() - t0
+        with span("repro.mbr"):
+            pairs = self.candidates(predicate)
         stats.n_candidates = len(pairs)
         if len(pairs) == 0:
             return np.zeros((0, 2), np.int64), stats
 
-        t0 = time.perf_counter()
-        verdicts = self.filter.verdicts(
-            self.approx_r, self.approx_s, pairs, predicate=predicate,
-            backend=self.filter_backend, **self.filter_opts)
-        stats.t_filter = time.perf_counter() - t0
+        with span("repro.filter"):
+            verdicts = self.filter.verdicts(
+                self.approx_r, self.approx_s, pairs, predicate=predicate,
+                backend=self.filter_backend, **self.filter_opts)
         _apply_verdicts(stats, verdicts)
 
-        t0 = time.perf_counter()
-        indec = pairs[verdicts == INDECISIVE]
-        ref = self._refine(predicate, indec)
-        stats.t_refine = time.perf_counter() - t0
+        with span("repro.refine"):
+            indec = pairs[verdicts == INDECISIVE]
+            ref = self._refine(predicate, indec)
 
         results = np.concatenate([pairs[verdicts == TRUE_HIT], indec[ref]],
                                  axis=0)

@@ -39,7 +39,7 @@ import numpy as np
 
 from ..core import geometry
 from ..core.geometry import polygon_edges, segments_intersect, size_buckets
-from ..kernels import note_routed
+from ..kernels import note_routed, to_device
 
 __all__ = [
     "REFINE_BACKENDS", "refine", "refine_pair",
@@ -721,19 +721,18 @@ def device_geometry(D, kind: str = "polygon") -> dict:
     incremental dataset patches swap the array and naturally invalidate.
     """
     import jax
-    import jax.numpy as jnp
     key = (id(D.verts), kind)
     cached = getattr(D, "_device_geom", None)
     if cached is not None and cached[0] == key:
         return cached[1]
     with jax.enable_x64(True):
         geom = {
-            "verts": jnp.asarray(np.asarray(D.verts, np.float64)),
-            "nverts": jnp.asarray(np.asarray(D.nverts, np.int32)),
+            "verts": to_device(np.asarray(D.verts, np.float64)),
+            "nverts": to_device(np.asarray(D.nverts, np.int32)),
         }
         if kind != "line":
             reps = geometry.representative_points(D.verts, D.nverts)
-            geom["reps"] = jnp.asarray(np.asarray(reps, np.float64))
+            geom["reps"] = to_device(np.asarray(reps, np.float64))
     try:
         D._device_geom = (key, geom)
     except AttributeError:      # slotted handle: still correct, just colder

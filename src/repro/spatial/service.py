@@ -45,6 +45,7 @@ from ..core.april import AprilStore
 from ..core.ri import RIStore
 from ..core.rasterize import Extent, GLOBAL_EXTENT
 from ..datagen.synthetic import PolygonDataset
+from ..runtime.trace import span, trace_block
 from .filters import get_filter
 from .mbr_join import MBRIndex
 from .plan import JoinPlan
@@ -79,8 +80,17 @@ class JoinTicket:
 
     ``pairs`` is [K, 2] int64 — (data object id, local query index) for the
     request's query polygons; ``stats`` is the executed group's
-    ``JoinStats.to_dict()`` envelope (shared by every request in the
-    micro-batch); ``latency`` is submit-to-resolution seconds.
+    ``JoinStats.to_dict()`` envelope (the same for every request in the
+    micro-batch but for ``extra["queue_wait_s"]``, this request's seconds
+    from submit to the drain that took it; ``extra["batch_compiles"]``
+    counts the programs the whole micro-batch built, store and planning
+    included, whether compiled or loaded from the persistent compile cache
+    — a process loads only programs it has not run before — and
+    ``extra["batch_cache_loads"]`` how many of them were loaded);
+    ``latency`` is submit-to-resolution seconds.
+    The micro-batch runs under the span ``repro.serve.batch``, its steps
+    under ``repro.serve.store``, ``repro.serve.execute`` and
+    ``repro.serve.scatter``.
     """
     dataset_id: str
     predicate: str
@@ -212,7 +222,7 @@ class JoinService:
         self._worker: threading.Thread | None = None
         self._stop = threading.Event()
         self._latencies: list[float] = []
-        # cumulative per-stage device-time breakdown across executed groups
+        # cumulative per-stage host-time breakdown across executed groups
         # (JoinStats.stage_times of every batch, summed)
         self._stage_times: dict[str, float] = {}
         self.stats = {"requests": 0, "batches": 0, "batched_requests": 0,
@@ -337,13 +347,14 @@ class JoinService:
             self._have_work.clear()
         if not batch:
             return 0
+        t_drain = time.perf_counter()
         groups: dict[tuple, list[_Request]] = {}
         for req in batch:
             key = (req.ticket.dataset_id, req.exec_predicate, req.method,
                    req.n_order)
             groups.setdefault(key, []).append(req)
         for (did, predicate, method, n_order), reqs in groups.items():
-            self._run_group(did, predicate, method, n_order, reqs)
+            self._run_group(did, predicate, method, n_order, reqs, t_drain)
         with self._lock:
             self.stats["batches"] += len(groups)
             self.stats["batched_requests"] += len(batch)
@@ -374,64 +385,71 @@ class JoinService:
         return choice
 
     def _run_group(self, dataset_id: str, predicate: str, method: str,
-                   n_order: int, reqs: list[_Request]) -> None:
-        with self._exec_lock:
-            handle = self._handle(dataset_id)
-            vmax = max(r.verts.shape[1] for r in reqs)
-            q_verts = np.concatenate(
-                [_pad_verts(r.verts, vmax) for r in reqs])
-            q_nverts = np.concatenate([r.nverts for r in reqs])
-            queries = PolygonDataset(name="_queries", verts=q_verts,
-                                     nverts=q_nverts)
-            if self.plan_mode == "adaptive":
-                # the planner's pick overrides the request's method/n_order;
-                # its warm store lands in the same LRU, so several chosen
-                # configs stay resident side by side
-                choice = self._plan_for(handle, dataset_id, predicate,
-                                        method, n_order, queries)
-                approx = self.warm_store(dataset_id, choice.method,
-                                         choice.n_order)
-                plan = JoinPlan(handle.dataset, queries,
-                                filter=choice.method,
-                                n_order=choice.n_order, extent=handle.extent,
-                                filter_backend=self.filter_backend,
-                                refine_backend=self.refine_backend,
-                                mbr_backend=self.mbr_backend,
-                                mbr_index=handle.index,
-                                pipeline_mode=self.pipeline_mode,
-                                plan_mode="adaptive", plan_choice=choice)
-            else:
-                approx = self.warm_store(dataset_id, method, n_order)
+                   n_order: int, reqs: list[_Request],
+                   t_drain: float) -> None:
+        with trace_block() as block, span("repro.serve.batch"):
+            with self._exec_lock:
+                handle = self._handle(dataset_id)
+                vmax = max(r.verts.shape[1] for r in reqs)
+                q_verts = np.concatenate(
+                    [_pad_verts(r.verts, vmax) for r in reqs])
+                q_nverts = np.concatenate([r.nverts for r in reqs])
+                queries = PolygonDataset(name="_queries", verts=q_verts,
+                                         nverts=q_nverts)
+                with span("repro.serve.store"):
+                    choice = None
+                    if self.plan_mode == "adaptive":
+                        # the planner's pick overrides the request's
+                        # method/n_order; its warm store lands in the same
+                        # LRU, so several chosen configs stay resident side
+                        # by side
+                        choice = self._plan_for(handle, dataset_id,
+                                                predicate, method, n_order,
+                                                queries)
+                        method, n_order = choice.method, choice.n_order
+                    approx = self.warm_store(dataset_id, method, n_order)
+                planned = ({} if choice is None else
+                           {"plan_mode": "adaptive", "plan_choice": choice})
                 plan = JoinPlan(handle.dataset, queries, filter=method,
                                 n_order=n_order, extent=handle.extent,
                                 filter_backend=self.filter_backend,
                                 refine_backend=self.refine_backend,
                                 mbr_backend=self.mbr_backend,
                                 mbr_index=handle.index,
-                                pipeline_mode=self.pipeline_mode)
-            plan.build(prebuilt=(approx, None))
-            pairs, stats = plan.execute(predicate)
-            stats.extra["batched_requests"] = len(reqs)
-            stats.extra["cache"] = dict(self.cache.stats)
-        with self._lock:
-            for key, dt in stats.stage_times().items():
-                self._stage_times[key] = self._stage_times.get(key, 0.0) + dt
-        envelope = stats.to_dict()
-        # scatter: each request owns a contiguous run of query indices
-        offs = np.cumsum([0] + [len(r.nverts) for r in reqs])
-        order = np.argsort(pairs[:, 1], kind="stable")
-        pairs = pairs[order]
-        bounds = np.searchsorted(pairs[:, 1], offs)
-        now = time.perf_counter()
-        for i, req in enumerate(reqs):
-            mine = pairs[bounds[i]: bounds[i + 1]].copy()
-            mine[:, 1] -= offs[i]
-            t = req.ticket
-            t.pairs, t.stats = mine, envelope
-            t.latency = now - req.t_submit
+                                pipeline_mode=self.pipeline_mode, **planned)
+                with span("repro.serve.execute"):
+                    plan.build(prebuilt=(approx, None))
+                    pairs, stats = plan.execute(predicate)
+                stats.extra["batched_requests"] = len(reqs)
+                stats.extra["cache"] = dict(self.cache.stats)
+                loads = block.counters.get("cache_loads", 0)
+                stats.extra["batch_compiles"] = (
+                    block.counters.get("compiles", 0) + loads)
+                stats.extra["batch_cache_loads"] = loads
             with self._lock:
-                self._latencies.append(t.latency)
-            t.done.set()
+                for key, dt in stats.stage_times().items():
+                    self._stage_times[key] = (self._stage_times.get(key, 0.0)
+                                              + dt)
+            envelope = stats.to_dict()
+            with span("repro.serve.scatter"):
+                # each request owns a contiguous run of query indices
+                offs = np.cumsum([0] + [len(r.nverts) for r in reqs])
+                order = np.argsort(pairs[:, 1], kind="stable")
+                pairs = pairs[order]
+                bounds = np.searchsorted(pairs[:, 1], offs)
+                now = time.perf_counter()
+                for i, req in enumerate(reqs):
+                    mine = pairs[bounds[i]: bounds[i + 1]].copy()
+                    mine[:, 1] -= offs[i]
+                    t = req.ticket
+                    t.pairs = mine
+                    t.stats = dict(envelope, extra=dict(
+                        envelope["extra"],
+                        queue_wait_s=t_drain - req.t_submit))
+                    t.latency = now - req.t_submit
+                    with self._lock:
+                        self._latencies.append(t.latency)
+                    t.done.set()
 
     # -- background micro-batching worker -----------------------------------
 
@@ -467,9 +485,10 @@ class JoinService:
 
     def latency_stats(self) -> dict:
         """p50/p99 submit-to-resolution latency over resolved requests,
-        plus the cumulative per-stage device-time breakdown
+        plus the cumulative per-stage host-time breakdown
         (``t_mbr``/``t_filter``/``t_refine``/``t_sync``) of the executed
-        batches."""
+        batches: in fused mode the stage times are dispatch only and the
+        device work lands in ``t_sync``."""
         with self._lock:
             lat = np.asarray(self._latencies, np.float64)
             stages = dict(self._stage_times)
