@@ -15,7 +15,8 @@ Three execution styles:
   survivors — compacted, like refinement's CMBR sweep — into the expensive
   full-cell joins. Backends: ``numpy`` evaluates the overlap as one flat
   row-keyed searchsorted pass (no padding, no per-pair loop); ``jnp``
-  gathers padded power-of-two width buckets on device; ``pallas`` ships
+  gathers padded power-of-two width buckets on device and tests overlap
+  by dense rank counts (no search loop); ``pallas`` ships
   bucketed batches through ``kernels/interval_join`` (the fused kernel
   computes the whole three-join verdict in one pass).
 * **Legacy padded batch joins** (`batch_overlap_np`, `batch_overlap_jnp`,
@@ -265,19 +266,24 @@ def batch_overlap_np(xs, xl, nx, ys, yl, ny) -> np.ndarray:
 
 
 def batch_overlap_jnp(xs, xl, nx, ys, yl, ny):
-    """jnp device version of :func:`batch_overlap_np` (vmapped searchsorted)."""
+    """jnp device version of :func:`batch_overlap_np`: a rank count, with no
+    search loop and no gather.
+
+    Per valid x interval, ``a`` counts the valid y intervals that end
+    before x starts and ``b`` those that start by x's end. A row's y starts
+    and y lasts are both sorted, so both sets are prefixes of Y with
+    ``a <= b``, and y_a overlaps x iff ``b > a``. Each count is a [B, Wx,
+    Wy] broadcast compare summed over Wy.
+    """
     assert jnp is not None
-
-    def one(xs_r, xl_r, nx_r, ys_r, yl_r, ny_r):
-        I = xs_r.shape[0]
-        j = jnp.searchsorted(yl_r, xs_r, side="left")
-        ok = j < ny_r
-        jj = jnp.minimum(j, jnp.maximum(ny_r - 1, 0))
-        ys_at = jnp.take(ys_r, jj)
-        valid_x = jnp.arange(I, dtype=jnp.int32) < nx_r
-        return jnp.any(valid_x & ok & (ys_at <= xl_r))
-
-    return jax.vmap(one)(xs, xl, nx, ys, yl, ny)
+    valid_y = (jnp.arange(ys.shape[1], dtype=jnp.int32)
+               < ny[:, None])[:, None, :]
+    a = jnp.sum(valid_y & (yl[:, None, :] < xs[:, :, None]), axis=2,
+                dtype=jnp.int32)
+    b = jnp.sum(valid_y & (ys[:, None, :] <= xl[:, :, None]), axis=2,
+                dtype=jnp.int32)
+    valid_x = jnp.arange(xs.shape[1], dtype=jnp.int32) < nx[:, None]
+    return jnp.any(valid_x & (b > a), axis=1)
 
 
 def _containment_batch_np(xs, xl, nx, fs, fl, nf) -> np.ndarray:
@@ -624,7 +630,7 @@ def _bucketed_rows_jnp(kind: str, X: IntervalLists, xi, Y: IntervalLists,
 
     Rows group by the power-of-two class of their wider list (padding waste
     <= 2x); each bucket pads its batch to a power of two so the jitted
-    gather+searchsorted step compiles O(log^2) times, not per shape. The
+    gather-and-test step compiles O(log^2) times, not per shape. The
     flat endpoint arrays live on device (:meth:`IntervalLists.device`);
     only the [B] row offsets/counts travel per call.
     """
@@ -837,6 +843,15 @@ def _fused_line_bucket_jnp(c_s, c_l, clo, ccnt, ya_s, ya_l, yalo, yacnt,
                      jnp.where(fhit, TRUE_HIT, INDECISIVE)).astype(jnp.int8)
 
 
+#: the (x, y) list widths of each overlap test a fused bucket program runs
+#: (``within``'s containment test is not one)
+_FUSED_OVERLAP_TESTS = {
+    "intersects": (("Wxa", "Wya"), ("Wxa", "Wyf"), ("Wxf", "Wya")),
+    "within": (("Wxa", "Wya"),),
+    "linestring": (("Wc", "Wya"), ("Wc", "Wyf")),
+}
+
+
 def _fused_status_fn(kind: str):
     if jax is None:  # pragma: no cover
         raise RuntimeError("jax unavailable for the fused filter stage")
@@ -878,8 +893,11 @@ def fused_status_rows(predicate: str, Xa: IntervalLists,
     Each bucket program gathers ``Bp`` padded rows of every list at its
     power-of-two width ``W``: the trace block counts the programs
     (``filter_buckets``), the live rows (``filter_rows``), the padded rows
-    (``filter_padded_rows``) and the gathered bytes (``filter_gather_bytes``,
-    ``Bp * sum(W) * 8``: a start and a last, int32 each, per slot). Each
+    (``filter_padded_rows``), the gathered bytes (``filter_gather_bytes``,
+    ``Bp * sum(W) * 8``: a start and a last, int32 each, per slot) and the
+    rank counts' compares (``filter_compare_ops``, ``Bp * 2 * Wx * Wy``
+    summed over the bucket's overlap tests: two counts per x interval, each
+    over every y slot). Each
     bucket's span ``repro.filter.bucket`` holds ``repro.filter.args``, its
     host-only argument build, and ``repro.filter.dispatch``, the uploads,
     the program call and the lane scatter, which can block while the
@@ -938,6 +956,8 @@ def fused_status_rows(predicate: str, Xa: IntervalLists,
             count("filter_rows", len(sel))
             count("filter_padded_rows", Bp)
             count("filter_gather_bytes", Bp * sum(kw.values()) * 8)
+            count("filter_compare_ops", Bp * 2 * sum(
+                kw[x] * kw[y] for x, y in _FUSED_OVERLAP_TESTS[predicate]))
     return lane
 
 

@@ -10,6 +10,9 @@ envelope.
 import numpy as np
 import pytest
 
+from repro.core.join import (IntervalLists, april_trichotomy_rows,
+                             fused_status_rows, linestring_trichotomy_rows,
+                             within_trichotomy_rows)
 from repro.datagen import make_dataset, make_linestrings
 from repro.datagen.synthetic import PolygonDataset
 from repro.spatial import PIPELINE_MODES, JoinPlan
@@ -87,6 +90,50 @@ def test_pipeline_mode_validation():
         JoinPlan(make_dataset("T9", seed=1, count=4),
                  make_dataset("T9", seed=2, count=4),
                  pipeline_mode="streamed")
+
+
+# --- wide interval lists through the fused status lane ---------------------
+
+def _wide_lists(rng, sizes, unit=False):
+    """IntervalLists over random sorted disjoint lists of the given sizes
+    (intervals 1-3 cells long, 4 apart or more, in one id range; unit cells
+    when ``unit``), and F lists that are subsets of them."""
+    a, f = [], []
+    for n in sizes:
+        starts = 4 * np.sort(rng.choice(1 << 14, size=n, replace=False))
+        ints = np.stack([starts, starts + rng.integers(1, 4, n)],
+                        axis=1).astype(np.uint64)
+        a.append(ints)
+        f.append(ints[rng.random(n) < 0.5])
+    off = [np.concatenate([[0], np.cumsum([len(x) for x in l])])
+           for l in (a, f)]
+    if unit:
+        return IntervalLists.from_unit_cells(off[0], np.concatenate(
+            [x[:, 0] for x in a]))
+    return (IntervalLists.from_intervals(off[0], np.concatenate(a)),
+            IntervalLists.from_intervals(off[1], np.concatenate(f)))
+
+
+@pytest.mark.parametrize("predicate", ("intersects", "within", "linestring"))
+def test_fused_identical_to_staged_wide_lists(predicate):
+    """A row whose A list is wider than 1024 intervals takes the fused
+    status lane's widest bucket; its verdicts equal the staged host
+    drivers' row for row."""
+    rng = np.random.default_rng(1400)
+    xa, xf = _wide_lists(rng, [1, 3, 8, 20, 40, 5])
+    ya, yf = _wide_lists(rng, [1500, 2, 30, 1100, 7])
+    ri, si = np.meshgrid(np.arange(6), np.arange(5), indexing="ij")
+    ri, si = ri.ravel(), si.ravel()
+    if predicate == "intersects":
+        want = april_trichotomy_rows(xa, xf, ya, yf, ri, si)
+    elif predicate == "within":
+        want = within_trichotomy_rows(xa, ya, yf, ri, si)
+    else:
+        xa, xf = _wide_lists(rng, [4, 60, 9, 300, 1, 25], unit=True), None
+        want = linestring_trichotomy_rows(xa, ya, yf, ri, si)
+    got = np.asarray(fused_status_rows(predicate, xa, xf, ya, yf, ri, si))
+    np.testing.assert_array_equal(got, want)
+    assert len(set(want[si == 0])) > 1      # the wide row decides both ways
 
 
 # --- property: random polygon batches -------------------------------------

@@ -66,15 +66,18 @@ def _recount(plan) -> dict:
     live = (counts[0] > 0) & (counts[2] > 0)
     widths = np.maximum.reduce(counts)
     out = {"filter_buckets": 0, "filter_rows": 0, "filter_padded_rows": 0,
-           "filter_gather_bytes": 0}
+           "filter_gather_bytes": 0, "filter_compare_ops": 0}
     for sel in size_buckets(np.where(live, np.maximum(widths, 1), 0),
                             _BUCKET_CHUNK):
         Bp = _pow2(len(sel))
-        W = sum(_pow2(max(1, c[sel].max())) for c in counts)
+        wxa, wxf, wya, wyf = (_pow2(max(1, c[sel].max())) for c in counts)
         out["filter_buckets"] += 1
         out["filter_rows"] += len(sel)
         out["filter_padded_rows"] += Bp
-        out["filter_gather_bytes"] += Bp * W * 8
+        out["filter_gather_bytes"] += Bp * (wxa + wxf + wya + wyf) * 8
+        # AA, AF and FA: two compares per x slot and y slot
+        out["filter_compare_ops"] += Bp * 2 * (wxa * wya + wxa * wyf
+                                               + wxf * wya)
         # per list: offsets and counts, int32 on the device (JAX narrows
         # the 64-bit host offsets); the lane scatter's int32 row indices
         h2d += 4 * Bp * (4 + 4) + 4 * len(sel)
@@ -91,6 +94,7 @@ def test_counters_equal_an_independent_recount(plan):
     assert _work(st.extra["counters"]) == _recount(plan)
     assert st.extra["counters"]["filter_padded_rows"] \
         >= st.extra["counters"]["filter_rows"]
+    assert st.extra["counters"]["filter_compare_ops"] > 0
 
 
 def test_spans_cover_every_host_step(plan):

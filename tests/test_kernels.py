@@ -7,7 +7,8 @@ import jax.numpy as jnp
 
 from repro.core import geometry
 from repro.core.april import build_april
-from repro.core.join import interval_join_pair, pack_lists
+from repro.core.join import (batch_overlap_jnp, batch_overlap_np,
+                             interval_join_pair, pack_lists)
 from repro.datagen import make_dataset
 from repro.kernels.april_attention.ops import april_attention, build_block_intervals
 from repro.kernels.april_attention.ref import april_attention_ref, dense_mask
@@ -39,8 +40,11 @@ def _random_interval_batch(rng, B, I, J, spread=10_000):
 
 
 @pytest.mark.parametrize("B,I,J", [(5, 3, 4), (16, 64, 64), (9, 17, 130),
-                                   (8, 128, 256), (3, 1, 1)])
+                                   (8, 128, 256), (3, 1, 1), (7, 40, 3),
+                                   (6, 3, 40), (4, 32, 1024)])
 def test_interval_join_kernel_sweep(B, I, J):
+    """The kernel, the jnp rank count and the host pass agree with the
+    oracle; Wx != Wy both ways, up to a 1024-wide y list."""
     rng = np.random.default_rng(B * 1000 + I + J)
     xs, xl, nx, ys, yl, ny = _random_interval_batch(rng, B, I, J)
     got = np.asarray(batch_interval_overlap(xs, xl, nx, ys, yl, ny,
@@ -49,6 +53,67 @@ def test_interval_join_kernel_sweep(B, I, J):
         jnp.asarray(xs), jnp.asarray(xl), jnp.asarray(nx),
         jnp.asarray(ys), jnp.asarray(yl), jnp.asarray(ny)))
     np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        np.asarray(batch_overlap_jnp(xs, xl, nx, ys, yl, ny)), want)
+    np.testing.assert_array_equal(batch_overlap_np(xs, xl, nx, ys, yl, ny),
+                                  want)
+
+
+I32_MIN, I32_MAX = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+
+#: hand-made rows, ``(x intervals, y intervals, overlap)`` each, inclusive
+#: lasts; a case's rows are padded to 8 with all-padding rows (count 0)
+OVERLAP_EDGES = {
+    "x_empty": [([], [(0, 9)], False), ([(3, 4)], [(0, 9)], True)],
+    "y_empty": [([(0, 9)], [], False), ([(0, 9), (20, 29)], [], False)],
+    "xs_touches_yl": [([(10, 20)], [(5, 10)], True),
+                      ([(10, 20)], [(5, 9), (21, 30)], False)],
+    "ys_touches_xl": [([(10, 20)], [(20, 30)], True),
+                      ([(0, 3), (10, 19)], [(20, 30)], False)],
+    "near_int32_min": [([(I32_MIN, I32_MIN)], [(I32_MIN, I32_MIN + 1)], True),
+                       ([(I32_MIN + 2, I32_MIN + 3)],
+                        [(I32_MIN, I32_MIN + 1)], False)],
+    "near_int32_max": [([(I32_MAX - 1, I32_MAX - 1)],
+                        [(I32_MAX - 2, I32_MAX - 1)], True),
+                       ([(I32_MAX - 1, I32_MAX - 1)],
+                        [(I32_MAX - 4, I32_MAX - 2)], False),
+                       ([(0, 5), (I32_MAX - 3, I32_MAX - 2)],
+                        [(I32_MAX - 1, I32_MAX - 1)], False)],
+    "wide_y_gaps": [([(1001, 1001)], [(3 * j, 3 * j + 1)
+                                      for j in range(600)], False),
+                    ([(1000, 1000)], [(3 * j, 3 * j + 1)
+                                      for j in range(600)], True)],
+    "all_padding": [],
+}
+
+
+@pytest.mark.parametrize("case", sorted(OVERLAP_EDGES))
+def test_batch_overlap_edge_rows(case):
+    """The jnp rank count equals the host pass and the stated verdict on
+    touching intervals, empty lists, int32 extremes and padding rows."""
+    rows = OVERLAP_EDGES[case]
+    Bp = 8
+    I = max([len(x) for x, _, _ in rows] + [1])
+    J = max([len(y) for _, y, _ in rows] + [1])
+
+    def pack(lists, W):
+        s = np.full((Bp, W), I32_MAX, np.int32)
+        l = s.copy()
+        n = np.zeros(Bp, np.int32)
+        for b, ints in enumerate(lists):
+            n[b] = len(ints)
+            for j, (a, z) in enumerate(ints):
+                s[b, j], l[b, j] = a, z
+        return s, l, n
+
+    xs, xl, nx = pack([x for x, _, _ in rows], I)
+    ys, yl, ny = pack([y for _, y, _ in rows], J)
+    want = np.zeros(Bp, bool)
+    want[:len(rows)] = [hit for _, _, hit in rows]
+    np.testing.assert_array_equal(batch_overlap_np(xs, xl, nx, ys, yl, ny),
+                                  want)
+    np.testing.assert_array_equal(
+        np.asarray(batch_overlap_jnp(xs, xl, nx, ys, yl, ny)), want)
 
 
 def test_interval_join_kernel_vs_merge_join():
