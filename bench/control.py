@@ -5,10 +5,11 @@ geometry the configuration states, must come out not correct.
 
     python3 bench/control.py --workload t1t2.join --seeds 1 2 3
 
-For each seed it generates the cell's layers at the cell's own size,
-computes the float32 reference's answer over every R object, hands it to
-the benchmark's comparison as the window's one answer, and prints the
-numbers compared. It needs no chip and touches no program code.
+For each seed it has the cell's traffic driver generate its data at the
+cell's own size and compute its plain reference's answer in float32,
+hands that to the driver's comparison as the window's one answer, and
+prints the numbers compared. It needs no chip and touches no program
+code.
 """
 from __future__ import annotations
 
@@ -21,11 +22,13 @@ BENCH = Path(__file__).resolve().parent
 
 
 def control_checks(cell, seed: int, mode: str = "f32") -> dict:
-    from harness import datagen, mixes, reference
+    """The cell's checks with its driver's own reference, in ``mode``, as
+    the window's one answer over the driver's own data."""
+    from harness import mixes
     drv = mixes.make(cell.traffic["kind"], cell.config, cell.traffic,
                      seed)
-    drv.geo = datagen.deployment(cell.config, seed)
-    drv.results = [reference.pairs(drv.geo["r"], drv.geo["s"], mode)]
+    drv.geo = drv.data(seed)
+    drv.results = [drv.reference(mode)]
     return drv.check()
 
 

@@ -22,6 +22,9 @@ BUILD_SECONDS = 8.0
 class Driver:
     """Repeated whole joins of two built layers."""
 
+    CHECKS = ("missing_pairs", "extra_pairs", "repeated_pairs",
+              "failed_joins")
+
     def __init__(self, config: dict, traffic: dict, seed: int):
         self.config = config
         self.predicate = traffic["predicate"]
@@ -32,6 +35,17 @@ class Driver:
         self.stats: list = []
         self.failed = 0
         self.error = ""
+
+    # -- data and the plain reference ----------------------------------------
+
+    def data(self, seed: int) -> dict:
+        """Both layers and their near misses (:func:`datagen.deployment`)."""
+        return datagen.deployment(self.config, seed)
+
+    def reference(self, mode: str) -> np.ndarray:
+        """The reference's ``intersects`` pairs over ``self.geo``, in
+        ``mode`` (``exact`` or ``f32``)."""
+        return reference.pairs(self.geo["r"], self.geo["s"], mode)
 
     # -- set-up -------------------------------------------------------------
 
@@ -48,7 +62,7 @@ class Driver:
         and then back to back for ``BUILD_SECONDS`` (``build_s``), and run
         one warm join, which compiles or loads every program the window
         uses. Returns the set-up's end-to-end readings."""
-        self.geo = datagen.deployment(self.config, self.seed)
+        self.geo = self.data(self.seed)
         with span("bench.build_first"):
             self._new_plan().build()
         builds, t0 = 0, time.perf_counter()
@@ -119,7 +133,7 @@ class Driver:
         """Compare the whole pair set of every join of the window with
         the float64 reference over every R object: {name: (value,
         limit)}."""
-        want = reference.pairs(self.geo["r"], self.geo["s"])
+        want = self.reference("exact")
         n_s = len(self.geo["s"][1])
         bad = {"missing_pairs": 0, "extra_pairs": 0, "repeated_pairs": 0}
         for pairs in self.results:

@@ -7,8 +7,6 @@ the tests call it on the CPU at a tiny size.
 from __future__ import annotations
 
 import contextlib
-import functools
-import importlib
 import json
 import time
 from pathlib import Path
@@ -56,34 +54,6 @@ def counting():
     finally:
         jax.monitoring.unregister_event_duration_listener(c.duration)
         jax.monitoring.unregister_event_listener(c.event)
-
-
-@contextlib.contextmanager
-def host_spans(entries: list):
-    """Wrap the program functions that ``entries`` name (``module``,
-    ``attr``, ``span``) in profiler spans, and restore them on exit. A
-    function that is not there is skipped."""
-    import jax
-    undo = []
-    try:
-        for e in entries:
-            try:
-                mod = importlib.import_module(e["module"])
-                fn = getattr(mod, e["attr"])
-            except (ImportError, AttributeError):
-                continue
-
-            def wrapped(*a, _fn=fn, _name=e["span"], **kw):
-                with jax.profiler.TraceAnnotation(_name):
-                    return _fn(*a, **kw)
-
-            functools.update_wrapper(wrapped, fn)
-            setattr(mod, e["attr"], wrapped)
-            undo.append((mod, e["attr"], fn))
-        yield
-    finally:
-        for mod, attr, fn in reversed(undo):
-            setattr(mod, attr, fn)
 
 
 def _peak_bytes(devices) -> int | None:
@@ -141,26 +111,23 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
                        seed)
     span = (jax.profiler.TraceAnnotation if traced
             else lambda name: contextlib.nullcontext())
-    entries = (spec.load_json(spec.BENCH / "spans.json")["spans"]
-               if traced else [])
-    with host_spans(entries):
-        with counting() as c_setup:
-            e2e = drv.setup(span)
-        e2e["setup_s"] = time.perf_counter() - t_start
-        log(f"setup: {e2e['setup_s']:.3f} s, build {e2e['build_s']:.3f} s, "
-            f"{c_setup}")
+    with counting() as c_setup:
+        e2e = drv.setup(span)
+    e2e["setup_s"] = time.perf_counter() - t_start
+    log("setup: " + ", ".join(f"{k} {v:.4f}" for k, v in e2e.items())
+        + f", {c_setup}")
+    if traced:
+        trace_dir.mkdir(parents=True, exist_ok=True)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.enable_hlo_proto = False
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        with counting() as c_win, span("bench.window"):
+            e2e.update(drv.window(seconds, span))
+    finally:
         if traced:
-            trace_dir.mkdir(parents=True, exist_ok=True)
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            opts.enable_hlo_proto = False
-            jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
-        try:
-            with counting() as c_win, span("bench.window"):
-                e2e.update(drv.window(seconds, span))
-        finally:
-            if traced:
-                jax.profiler.stop_trace()
+            jax.profiler.stop_trace()
     log(f"window: {drv.attempted} attempted, {drv.failed} failed, "
         f"{c_win}")
     if drv.error:
@@ -170,9 +137,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     metrics, breakdown, device = {}, None, {}
     if traced:
         ctx = _layer_context(cell, drv, devices, trace_dir, peaks)
-        log("work per join: " + json.dumps(
-            {k: ctx[k] for k in ("mbr_candidates", "filter_bytes",
-                                 "filter_comparisons")}))
+        log("layer inputs: " + json.dumps(
+            {k: v for k, v in ctx.items() if isinstance(v, (int, float))}))
         log("stage device s: " + json.dumps(ctx["stage_s"]))
         for m in cell.per_layer:
             value = cell.readers[m["name"]](ctx)

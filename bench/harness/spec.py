@@ -5,8 +5,10 @@ lives in a file of its own under ``bench/``:
 
 * a configuration: ``file`` of its entry (``bench/configs/<name>.json``);
 * a traffic mix: ``bench/traffic/<mix>.json``, whose ``kind`` names the
-  driver ``bench/harness/kinds/<kind>.py`` that reads it
-  (:mod:`harness.mixes`);
+  driver ``bench/harness/kinds/<kind>.py`` that reads it. The driver owns
+  the kind's ``data(seed)``, its plain ``reference(mode)`` and the names
+  of its ``CHECKS`` (:mod:`harness.mixes`); a new kind's data generator
+  and reference are new modules beside ``bench/harness/reference.py``;
 * a per-layer metric: ``bench/metrics/<metric>.py``, a module with one
   function ``read(ctx)`` that returns the reading or ``None``.
 

@@ -9,7 +9,9 @@ small hand-made event list. :func:`load` turns the ``.xplane.pb`` that
   line of each TPU device plane, ``program`` being the jitted program
   (HLO module) the op belongs to;
 * host spans: ``(name, start_ns, end_ns)`` of the ``TraceAnnotation``
-  spans the benchmark opened, from the host plane.
+  spans from the host plane: the benchmark's own (``bench.*``) and the
+  program's (``repro.*``, opened by ``repro.runtime.trace.span``), so an
+  idle gap is named by the program step the host was in.
 """
 from __future__ import annotations
 
@@ -19,6 +21,9 @@ import os
 import re
 
 __all__ = ["union_ns", "clip", "stage_ns", "top_ops", "idle_gaps", "load"]
+
+#: prefixes of the host events :func:`load` keeps as spans
+SPAN_PREFIXES = ("bench.", "repro.")
 
 
 def clip(intervals, lo: float, hi: float) -> list[tuple[float, float]]:
@@ -116,7 +121,7 @@ def _enclosing(modules, starts, t: float) -> str:
 def load(trace_dir: str):
     """(device ops per TPU plane {plane: [ops]}, host spans) from the
     newest ``.xplane.pb`` under ``trace_dir``. Host spans are the events
-    whose name starts with ``bench.``."""
+    whose name starts with one of ``SPAN_PREFIXES``."""
     from jax.profiler import ProfileData
     files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
@@ -146,7 +151,7 @@ def load(trace_dir: str):
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith("bench."):
+                    if ev.name.startswith(SPAN_PREFIXES):
                         spans.append((ev.name, ev.start_ns,
                                       ev.start_ns + ev.duration_ns))
     return devices, spans

@@ -10,7 +10,8 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from harness import runner, spec  # noqa: E402
+import box_within_kind  # noqa: E402
+from harness import mixes, runner, spec  # noqa: E402
 
 K = 0.05
 WORKLOADS = [w["name"] for w in spec.benchmark()["workloads"]]
@@ -28,19 +29,47 @@ def run(cell, tmp_path, traced=False, seed=2**32 + 3):
                       jax.devices()[:1], tmp_path / "trace", lambda m: None)
 
 
-@pytest.mark.parametrize("workload", WORKLOADS)
-def test_cell_agrees_with_the_reference(workload, tmp_path):
-    res = run(tiny(workload), tmp_path)
+def assert_agrees(res, cell):
+    """A sound run of ``cell``: correct, its end-to-end metrics, and the
+    checks its driver declares, each with a number and a limit."""
     assert res["correct"], res["checks"]
     assert res["attempted"] >= 1 and res["failed"] == 0
-    names = {m["name"] for m in spec.resolve(workload).end_to_end}
-    assert set(res["metrics"]) == names
+    assert set(res["metrics"]) == {m["name"] for m in cell.end_to_end}
     assert all(m["value"] > 0 for m in res["metrics"].values())
     assert list(res)[-1] == "checks"
-    assert set(res["checks"]) == {"missing_pairs", "extra_pairs",
-                                  "repeated_pairs", "failed_joins"}
+    drv = mixes.make(cell.traffic["kind"], cell.config, cell.traffic, 0)
+    assert list(res["checks"]) == list(drv.CHECKS)
+    for c in res["checks"].values():
+        assert set(c) == {"value", "limit"}
+        assert all(isinstance(c[k], (int, float)) for k in c)
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(
         res["device"])
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_cell_agrees_with_the_reference(workload, tmp_path):
+    cell = tiny(workload)
+    assert_agrees(run(cell, tmp_path), cell)
+
+
+def test_a_new_kind_is_held_to_its_declared_checks(tmp_path, monkeypatch):
+    box_within_kind.install(monkeypatch, mixes)
+    cell = box_within_kind.cell(spec)
+    assert_agrees(run(cell, tmp_path), cell)
+
+
+def test_a_new_kind_runs_traced_without_the_join_inputs(tmp_path,
+                                                        monkeypatch):
+    """Its set-up gives no ``build_s`` and its layer inputs none of the
+    join's work counts."""
+    box_within_kind.install(monkeypatch, mixes)
+    monkeypatch.setattr(runner, "peaks_for",
+                        lambda kind: {"hbm_bytes_per_s": 819e9})
+    cell = box_within_kind.cell(spec)
+    res = run(cell, tmp_path, traced=True)
+    assert res["correct"], res["checks"]
+    assert res["metrics"]["within.queries"]["value"] == res["attempted"]
+    assert res["device"]["window_s"] > 0
 
 
 def test_traced_run_reads_its_layers(tmp_path, monkeypatch):

@@ -9,8 +9,9 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
+import box_within_kind  # noqa: E402
 import control  # noqa: E402
-from harness import datagen, reference, spec  # noqa: E402
+from harness import datagen, mixes, reference, spec  # noqa: E402
 
 WORKLOADS = [w["name"] for w in spec.benchmark()["workloads"]]
 
@@ -32,6 +33,20 @@ def test_f32_control_fails(workload, seed):
 def test_exact_reference_in_the_programs_place_passes(workload):
     checks = control.control_checks(small(workload), 5, "exact")
     assert all(v <= lim for v, lim in checks.values())
+
+
+@pytest.mark.parametrize("seed", [1, 2**31 + 3])
+def test_control_of_a_new_kind_uses_its_own_data_and_reference(
+        seed, monkeypatch):
+    """A kind whose answer is not the intersects pair set: its exact
+    reference in the program's place passes, its float32 one fails."""
+    box_within_kind.install(monkeypatch, mixes)
+    cell = box_within_kind.cell(spec)
+    exact = control.control_checks(cell, seed, "exact")
+    assert exact == {"wrong_within_pairs": (0, 0)}
+    f32 = control.control_checks(cell, seed)
+    assert list(f32) == ["wrong_within_pairs"]
+    assert f32["wrong_within_pairs"][0] >= 256 // 2
 
 
 def test_reference_agrees_with_a_per_pair_loop():

@@ -59,8 +59,34 @@ def test_top_ops_ranks_by_total_time():
 def test_idle_gaps_are_named_by_the_innermost_span():
     ops = [("a", 10, 20, "p"), ("b", 60, 70, "p")]
     spans = [("bench.window", 0, 100), ("bench.join", 1, 99),
-             ("bench.host.mbr_frame", 25, 55)]
+             ("repro.join", 2, 98), ("repro.mbr", 22, 58),
+             ("repro.mbr.frame", 25, 55)]
     gaps = trace.idle_gaps(ops, spans, 0, 100, 10)
-    assert gaps[0] == ["bench.host.mbr_frame", 40e-9]
+    assert gaps[0] == ["repro.mbr.frame", 40e-9]
     assert [g[1] for g in gaps] == [40e-9, 30e-9, 10e-9]
-    assert gaps[1][0] == "bench.join"
+    assert gaps[1][0] == "repro.join"
+
+
+def test_load_keeps_the_benchmarks_and_the_programs_spans(tmp_path):
+    """A real profiler trace on the CPU: ``bench.*`` spans and the
+    program's own ``repro.*`` spans (opened inside a trace block, as
+    ``JoinPlan.execute`` opens one) are kept, other host events are not."""
+    import jax
+    from jax.profiler import TraceAnnotation
+
+    from repro.runtime.trace import span, trace_block
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("bench.window"), trace_block():
+            with span("repro.mbr.frame"):
+                jax.numpy.arange(8).sum().block_until_ready()
+            with TraceAnnotation("other.step"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    _, spans = trace.load(str(tmp_path))
+    names = {name for name, _, _ in spans}
+    assert {"bench.window", "repro.mbr.frame"} <= names
+    assert "other.step" not in names
+    assert all(n.startswith(trace.SPAN_PREFIXES) for n in names)
+    assert all(s <= e for _, s, e in spans)
